@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.attn_common import default_interpret
+from repro.kernels.common import default_interpret
 from repro.kernels.decode_gqa.ops import decode_attention
 from repro.kernels.decode_gqa.ref import decode_gqa_ref
 from repro.kernels.flash_attn.ops import flash_attention

@@ -94,7 +94,7 @@ def _resnet_eval_fns(params, cfg, key):
 
     def acc(pols, k):
         logits = resnet.forward(params, imgs, cfg, pols, k)
-        return (jnp.argmax(logits, -1) == labels).mean()
+        return noise_tolerance.top1_accuracy(logits, labels)
 
     def per_site_eval(sigma_vec, k):
         return acc([base.replace(sigma_chain=sigma_vec[i])
@@ -148,7 +148,7 @@ def _lm_eval_fns(arch_name, key):
 
     def acc(pol, k):
         logits, _, _ = tr.forward(params, batch, cfg, pol, key=k)
-        return (jnp.argmax(logits, -1) == batch["labels"]).mean()
+        return noise_tolerance.top1_accuracy(logits, batch["labels"])
 
     def per_layer_eval(sigma_vec, k):
         pol = NetworkPolicy(
@@ -230,11 +230,9 @@ def run() -> list[str]:
 
     mesh = _probe_mesh()
     # ~one probe-layer's worth of evals per chunk: bounds the live broadcast
-    # of the eval batch across probes while staying one jitted device call;
-    # rounded up to a multiple of the mesh data axis so the within-chunk
-    # probe axis actually shards (probe_spec replicates on non-divisibility)
-    dp = 1 if mesh is None else mesh.shape["data"]
-    chunk = -(-(len(SIGMAS) * N_REPEATS + 1) // dp) * dp
+    # of the eval batch across probes while staying one jitted device call
+    # (find_sigma_max_batched rounds it up to a multiple of the data axis)
+    chunk = len(SIGMAS) * N_REPEATS + 1
     t0 = time.perf_counter()
     res_sites = noise_tolerance.find_sigma_max_batched(
         counted_eval, SIGMAS, key, n_layers=n_sites, n_repeats=N_REPEATS,
@@ -261,7 +259,7 @@ def run() -> list[str]:
     # compiles (TPU); interpret-mode CPU CI records the ratio and gates on
     # correctness/structure only (traces == 1 above) — the interpreter's
     # per-grid-step overhead dominates both paths there.
-    from repro.kernels.td_vmm.td_vmm import default_interpret
+    from repro.kernels.common import default_interpret
     timing_enforced = not default_interpret()
     if timing_enforced:
         assert t_batched < t_scalar_extrap, \
@@ -305,9 +303,7 @@ def run() -> list[str]:
     lm_eval, lm_net_eval, n_lm, lm_sites, lm_base = _lm_eval_fns(lm_name,
                                                                  key)
     # same chunking as the CNN sweep: an unchunked mesh= call would keep
-    # the whole (n_lm * (S*R+1)) probe batch live at once AND skip the
-    # data-axis rounding, silently replicating when the count doesn't
-    # divide the mesh
+    # the whole (n_lm * (S*R+1)) probe batch live at once
     res_lm_layers = noise_tolerance.find_sigma_max_batched(
         lm_eval, SIGMAS, key, n_layers=n_lm, n_repeats=N_REPEATS,
         chunk_size=chunk, mesh=mesh)

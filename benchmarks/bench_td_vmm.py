@@ -34,7 +34,7 @@ import numpy as np
 from repro.core import noise_tolerance
 from repro.kernels.td_vmm import ops as td_ops
 from repro.kernels.td_vmm import ref as td_ref
-from repro.kernels.td_vmm.td_vmm import default_interpret
+from repro.kernels.common import default_interpret
 from repro.tdsim import TDPolicy
 from repro.tdsim.td_linear import td_matmul_int
 
@@ -134,7 +134,7 @@ def _fig10_smoke_eval(engine: str):
         h = mm(x_int, w1, base.replace(sigma_chain=sigma_vec[0]), ka)
         h = jnp.clip(jnp.round(h / 64.0), -8, 7).astype(jnp.int32)
         logits = mm(h, w2, base.replace(sigma_chain=sigma_vec[1]), kb)
-        return (jnp.argmax(logits, -1) == labels).mean()
+        return noise_tolerance.top1_accuracy(logits, labels)
 
     return eval_fn
 

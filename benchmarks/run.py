@@ -16,6 +16,7 @@ from benchmarks import (bench_arch_energy, bench_attention, bench_chaos,
                         bench_roofline, bench_scenarios, bench_serving,
                         bench_td_vmm, bench_tdc, bench_tdmac_cell,
                         bench_throughput_area)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig3c": bench_eta_esnr,
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated suite keys (default: all)")
     args = ap.parse_args()
+    enable_compile_cache()
     keys = args.only.split(",") if args.only else list(SUITES)
     failed = []
     for k in keys:

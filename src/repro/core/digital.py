@@ -25,6 +25,7 @@ import math
 import jax.numpy as jnp
 
 from repro.core import constants as C
+from repro.core import tdc
 from repro.core.techlib import DEFAULT_LIB, TechLib
 
 
@@ -42,7 +43,7 @@ def _adder_bits_per_mac(n, bits: int):
             total += (bits + k) / 2.0 ** k
         return total
     nf = jnp.maximum(2.0, jnp.asarray(n, jnp.float32))
-    depth = jnp.maximum(1.0, jnp.ceil(jnp.log2(nf)))
+    depth = jnp.maximum(1.0, tdc.ceil_log2(nf))
     inv = 2.0 ** (-depth)
     return bits * (1.0 - inv) + 2.0 - (depth + 2.0) * inv
 

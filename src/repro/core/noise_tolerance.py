@@ -40,6 +40,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 # jitted probe-runners keyed by eval_fn (weak: dies with the eval), then by
 # chunk config — repeated sweeps over the same eval reuse the compiled
@@ -51,9 +52,9 @@ import numpy as np
 _JIT_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
-def _jitted_runner(eval_fn, chunk_size):
+def _jitted_runner(eval_fn, chunk_size, mesh=None):
     per_fn = _JIT_CACHE.setdefault(eval_fn, {})
-    fn = per_fn.get(chunk_size)
+    fn = per_fn.get((chunk_size, mesh))
     if fn is None:
         ref = weakref.ref(eval_fn)
 
@@ -63,13 +64,20 @@ def _jitted_runner(eval_fn, chunk_size):
                 raise ReferenceError("eval_fn was garbage-collected")
             return jax.vmap(target)(v, k)
 
-        if chunk_size is None:
-            fn = jax.jit(call)
-        else:
-            @jax.jit
-            def fn(cv, ck):
+        body = call
+        if chunk_size is not None:
+            def body(cv, ck):
                 return jax.lax.map(lambda c: call(c[0], c[1]), (cv, ck))
-        per_fn[chunk_size] = fn
+        if mesh is not None:
+            # Pallas kernels cannot be partitioned automatically: each
+            # device runs the eval on its own slice of the probe axis
+            from repro.launch.mesh import dp_axes
+            lead = (None,) if chunk_size is not None else ()
+            spec = P(*lead, dp_axes(mesh))
+            body = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=spec, check_vma=False)
+        fn = jax.jit(body)
+        per_fn[(chunk_size, mesh)] = fn
     return fn
 
 
@@ -95,6 +103,19 @@ class BatchedNoiseToleranceResult:
         return NoiseToleranceResult(self.sigmas, self.rel_drop[l],
                                     float(self.acc_clean[l]),
                                     float(self.sigma_max[l]))
+
+
+def top1_accuracy(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Mean of argmax(logits) == labels, the accuracy an eval_fn returns.
+
+    The logits pass an optimization barrier before the argmax.  Without
+    it the TPU compiler (jax 0.9.0 on a v5e) fuses the argmax into the
+    producing matmul and, at some vmapped probe counts (5, 7, 13, 26 in
+    the Fig. 10 LM sweep), returns an index that is not a maximum for
+    about half the rows, so a probe's accuracy depended on how many
+    probes shared its program."""
+    logits = jax.lax.optimization_barrier(logits)
+    return (jnp.argmax(logits, -1) == labels).mean()
 
 
 def crossing_sigma(sigmas: np.ndarray, rel_drop: np.ndarray,
@@ -161,34 +182,33 @@ def _run_probes(eval_fn, flat_v: jax.Array, flat_k: jax.Array,
     chunk_size evals are live at once.
 
     With `mesh`, the probe axis (the within-chunk axis when chunked) is
-    sharded over the mesh data axis (`launch.sharding.probe_spec`) before
-    the jitted call -- each probe is an independent eval, so the sweep
-    data-parallelizes across devices with no cross-probe collectives and
-    bit-identical per-probe results.
+    split over the mesh data axis by `jax.shard_map` -- each probe is an
+    independent eval, so the sweep data-parallelizes across devices with no
+    cross-probe collectives and bit-identical per-probe results.  The axis
+    is padded (with repeats of the first probe, discarded) to a multiple
+    of the data-axis size, so every device gets an equal share.
     """
     t = flat_v.shape[0]
-    if mesh is not None:
-        from repro.launch import sharding as sharding_mod
-    if chunk_size is None or chunk_size >= t:
-        if mesh is not None:
-            flat_v, flat_k = sharding_mod.shard_probes(mesh, (flat_v, flat_k))
-        return _jitted_runner(eval_fn, None)(flat_v, flat_k)
-    if chunk_size < 1:
+    if chunk_size is not None and chunk_size < 1:
         raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
-    pad = (-t) % chunk_size
+    n_dev = 1
+    if mesh is not None:
+        from repro.launch.mesh import dp_size
+        n_dev = dp_size(mesh)
+    chunked = chunk_size is not None and chunk_size < t
+    unit = -(-(chunk_size if chunked else t) // n_dev) * n_dev
+    pad = (-t) % unit
     if pad:
         flat_v = jnp.concatenate(
             [flat_v, jnp.broadcast_to(flat_v[:1], (pad,) + flat_v.shape[1:])])
         flat_k = jnp.concatenate(
             [flat_k, jnp.broadcast_to(flat_k[:1], (pad,) + flat_k.shape[1:])])
-    n_chunks = (t + pad) // chunk_size
-    cv = flat_v.reshape((n_chunks, chunk_size) + flat_v.shape[1:])
-    ck = flat_k.reshape((n_chunks, chunk_size) + flat_k.shape[1:])
-    if mesh is not None:
-        # chunks run sequentially (lax.map bounds live memory); the
-        # within-chunk probe axis shards over data
-        cv, ck = sharding_mod.shard_probes(mesh, (cv, ck), axis=1)
-    return _jitted_runner(eval_fn, chunk_size)(cv, ck).reshape(-1)[:t]
+    if not chunked:
+        return _jitted_runner(eval_fn, None, mesh)(flat_v, flat_k)[:t]
+    n_chunks = (t + pad) // unit
+    cv = flat_v.reshape((n_chunks, unit) + flat_v.shape[1:])
+    ck = flat_k.reshape((n_chunks, unit) + flat_k.shape[1:])
+    return _jitted_runner(eval_fn, unit, mesh)(cv, ck).reshape(-1)[:t]
 
 
 def find_sigma_max_batched(eval_fn: Callable[[jax.Array, jax.Array], jax.Array],
@@ -220,13 +240,13 @@ def find_sigma_max_batched(eval_fn: Callable[[jax.Array, jax.Array], jax.Array],
     first probe and discarded), each chunk vmapped -- still one jitted
     device program, results bit-identical to the unchunked call.
 
-    `mesh` shards the probe batch over the mesh data axis (the big-LM
-    per-layer sweep becomes mesh-parallel): probes are independent evals,
-    so sharding composes with `chunk_size` (the within-chunk axis shards;
+    `mesh` splits the probe batch over the mesh data axis with
+    `jax.shard_map` (the big-LM per-layer sweep becomes mesh-parallel; the
+    Pallas TD kernel cannot be partitioned automatically): probes are
+    independent evals, so sharding composes with `chunk_size` (the
+    within-chunk axis shards, rounded up to a multiple of the data axis;
     chunks stay sequential) and results are bit-identical to the unsharded
-    call.  Probe counts that do not divide the data-axis size replicate
-    (correct, just unsharded) -- pick chunk_size as a multiple of the data
-    axis for full utilization.
+    call.
     """
     sig = np.asarray(list(sigmas), np.float64)
     s, l, r = len(sig), int(n_layers), int(n_repeats)

@@ -57,11 +57,20 @@ def _tau_at(vdd, tau_unit: float):
     return cells.delay_at_vdd(jnp.asarray(tau_unit), jnp.asarray(vdd))
 
 
+def ceil_log2(x):
+    """Exact ceil(log2(x)) of positive float32 values, as float32, read off
+    their binary exponent.  ``jnp.ceil(jnp.log2(x))`` is not exact: a TPU's
+    float32 log2 is an approximation (log2(8192) = 13.000001 on a v5e), so
+    it adds a bit at a power of two."""
+    mant, expo = jnp.frexp(jnp.asarray(x, jnp.float32))
+    return (expo - (mant == 0.5)).astype(jnp.float32)
+
+
 def _lsb_bits(l_osc):
     """ceil(1 + log2(L_osc)) -- SAR bits covering the 2*L_osc LSB window."""
     if _is_scalar(l_osc):
         return math.ceil(1.0 + math.log2(l_osc))
-    return jnp.ceil(1.0 + jnp.log2(jnp.asarray(l_osc, jnp.float32)))
+    return 1.0 + ceil_log2(l_osc)
 
 
 # ---------------------------------------------------------------------------
@@ -92,7 +101,7 @@ def range_bits(range_steps):
     if _is_scalar(range_steps):
         return max(1, int(math.ceil(math.log2(max(2.0, range_steps)))))
     steps = jnp.maximum(2.0, jnp.asarray(range_steps, jnp.float32))
-    return jnp.maximum(1.0, jnp.ceil(jnp.log2(steps)))
+    return jnp.maximum(1.0, ceil_log2(steps))
 
 
 # ---------------------------------------------------------------------------

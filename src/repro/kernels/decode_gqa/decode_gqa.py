@@ -3,9 +3,10 @@ production attention engine for single-query-row decode steps.
 
 Decode with a long KV cache is the memory-roofline hot spot of the decode_*
 shapes: each step streams the whole KV cache from HBM once.  The kernel
-tiles the cache along S; each grid step loads a (bs, Hkv, D) KV block into
-VMEM, updates the online-softmax running (m, l, acc) held in VMEM scratch,
-and writes the normalized output on the last block.
+tiles the cache along S; each grid step loads one KV head's (bs, D) block
+into VMEM, scores the g = Hq/Hkv query heads of its group against it in
+one (g, D) x (D, bs) matmul, updates the online-softmax running (m, l, acc)
+held in VMEM scratch, and writes the normalized output on the last block.
 
 Runtime operand: ``length`` — the (B,) int32 cache fill level — rides in as
 an SMEM scalar operand, NOT a compile-time constant, and KV blocks past it
@@ -13,13 +14,17 @@ are skipped entirely at runtime via ``pl.when`` (the td_vmm bar: a decode
 loop over growing fill levels reuses ONE compiled program and never touches
 dead cache blocks).
 
-Grid: (B, S/bs).  Scratch: m/l (Hq,), acc (Hq, D) — persistent across the S
-axis for a fixed batch row (TPU grid is sequential over the last dim).
+Grid: (B, Hkv, S/bs).  The cache is viewed as (B, S, Hkv*D) (a free
+reshape) and KV head h is lane block h, so no head axis sits in the last
+two block dims; q and the output are viewed as (B, Hkv, g, D).  Scratch:
+m/l (g, 1), acc (g, D) — 2-D tiles, persistent across the S axis for one
+(batch row, KV head) (TPU grid is sequential over the last dim).  A head
+dim that is not a multiple of 128 lanes is zero-padded up to one.
 
-Interpret policy (`kernels.attn_common`): ``interpret=None`` compiles on a
-TPU backend and falls back to interpret mode elsewhere (CPU CI);
-``REPRO_ATTN_INTERPRET=0|1`` overrides both.  In interpret mode the default
-block is the whole (padded) cache; compiled default is 512.
+Interpret policy (`kernels.common`): ``interpret=None`` compiles on a TPU
+backend and runs in the Pallas interpreter elsewhere (CPU tests); both
+modes use the same block, 512 or the cache rounded up to 16 rows when it
+is shorter.
 
 Public surface
 --------------
@@ -39,14 +44,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.attn_common import NEG_INF, SCALAR_SPACE, default_interpret
+from repro.kernels.common import (LANES, NEG_INF, SCALAR_SPACE,
+                                  resolve_interpret, round_up)
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, bs: int, n_blocks: int):
+            *, bs: int, n_blocks: int, scale: float):
     i = pl.program_id(0)
-    blk = pl.program_id(1)
-    length = len_ref[i]                           # runtime scalar operand
+    blk = pl.program_id(2)
+    length = len_ref[0, i]                        # runtime scalar operand
 
     @pl.when(blk == 0)
     def _init():
@@ -57,43 +63,32 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     # runtime dead-block skip: blocks entirely past the cache fill level
     @pl.when(blk * bs < length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)              # (Hq, D)
-        k = k_ref[0].astype(jnp.float32)              # (bs, Hkv, D)
+        q = q_ref[0, 0].astype(jnp.float32) * scale   # (g, D)
+        k = k_ref[0].astype(jnp.float32)              # (bs, D)
         v = v_ref[0].astype(jnp.float32)
-        hq, d = q.shape
-        hkv = k.shape[1]
-        g = hq // hkv
-
-        qg = q.reshape(hkv, g, d) * (d ** -0.5)
-        sc = jnp.einsum("kgd,skd->kgs", qg, k)        # (Hkv, g, bs)
-        pos = jax.lax.broadcasted_iota(jnp.int32, (hkv, g, bs), 2) \
-            + blk * bs
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        pos = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1) + blk * bs
         mask = pos < length
-        sc = jnp.where(mask, sc, NEG_INF)
+        sc = jnp.where(mask, sc, NEG_INF)             # (g, bs)
 
-        m_prev = m_ref[...].reshape(hkv, g)
-        l_prev = l_ref[...].reshape(hkv, g)
-        acc_prev = acc_ref[...].reshape(hkv, g, d)
-
-        m_new = jnp.maximum(m_prev, sc.max(-1))
+        m_prev = m_ref[...]                           # (g, 1)
+        l_prev = l_ref[...]
+        m_new = jnp.maximum(m_prev, sc.max(-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # NEG_INF - NEG_INF == 0 in f32: zero masked entries explicitly
-        p = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
-        l_new = l_prev * alpha + p.sum(-1)
-        acc_new = acc_prev * alpha[..., None] \
-            + jnp.einsum("kgs,skd->kgd", p, v)
-
-        m_ref[...] = m_new.reshape(hq)
-        l_ref[...] = l_new.reshape(hq)
-        acc_ref[...] = acc_new.reshape(hq, d)
+        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+        l_ref[...] = l_prev * alpha + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
     # finalize reads the REFS (not compute-locals): the last cache block may
     # have been skipped as dead, so its locals never exist.
     @pl.when(blk == n_blocks - 1)
     def _finalize():
-        acc = acc_ref[...]                            # (Hq, D)
-        l = l_ref[...]                                # (Hq,)
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)   # (g, D)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def decode_gqa_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -101,16 +96,11 @@ def decode_gqa_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       interpret: bool | None = None) -> jnp.ndarray:
     """q (B, Hq, D); k/v (B, S, Hkv, D); length (B,) int32 RUNTIME operand.
 
-    ``interpret=None`` resolves via ``default_interpret()`` here, OUTSIDE
-    the jit, so the env override is honoured on every call."""
+    ``interpret`` resolves through `kernels.common.resolve_interpret` here,
+    OUTSIDE the jit."""
     s = k.shape[1]
-    if interpret is None:
-        interpret = default_interpret()
-    # interpret mode pays per grid step, not per byte of VMEM: default to a
-    # whole-cache block (grid = B); compiled mode to 512
-    if bs is None:
-        bs = s if interpret else 512
-    bs = min(bs, s)
+    interpret = resolve_interpret(interpret)
+    bs = min(bs or 512, round_up(s, 16))
     return _decode_gqa_call(q, k, v, length, bs=bs, interpret=interpret)
 
 
@@ -121,30 +111,33 @@ def _decode_gqa_call(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     b, hq, d = q.shape
     s, hkv = k.shape[1], k.shape[2]
     assert hq % hkv == 0, (hq, hkv)
-    n_blocks = -(-s // bs)
-    s_pad = n_blocks * bs
-    if s_pad != s:
-        k = jnp.pad(k, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
+    g = hq // hkv
+    dp = round_up(d, LANES)
+    s_pad = round_up(s, bs)
+    n_blocks = s_pad // bs
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, dp - d))).reshape(b, hkv, g, dp)
+    k = jnp.pad(k, ((0, 0), (0, s_pad - s), (0, 0), (0, dp - d)))
+    v = jnp.pad(v, ((0, 0), (0, s_pad - s), (0, 0), (0, dp - d)))
     # clamp to the true cache length: padded tail positions are never valid
-    lens = jnp.minimum(jnp.asarray(length, jnp.int32).reshape(b), s)
+    # a 2-D scalar operand stays one whole SMEM block under vmap
+    lens = jnp.minimum(jnp.asarray(length, jnp.int32).reshape(1, b), s)
 
-    kern = functools.partial(_kernel, bs=bs, n_blocks=n_blocks)
-    return pl.pallas_call(
+    kern = functools.partial(_kernel, bs=bs, n_blocks=n_blocks,
+                             scale=d ** -0.5)
+    head_spec = pl.BlockSpec((1, 1, g, dp), lambda i, h, j: (i, h, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, dp), lambda i, h, j: (i, j, h))
+    out = pl.pallas_call(
         kern,
-        grid=(b, n_blocks),
-        in_specs=[
-            pl.BlockSpec(memory_space=SCALAR_SPACE),
-            pl.BlockSpec((1, hq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, hq, d), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        grid=(b, hkv, n_blocks),
+        in_specs=[pl.BlockSpec(memory_space=SCALAR_SPACE), head_spec,
+                  kv_spec, kv_spec],
+        out_specs=head_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dp), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((hq,), jnp.float32),
-            pltpu.VMEM((hq,), jnp.float32),
-            pltpu.VMEM((hq, d), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, dp), jnp.float32),
         ],
         interpret=interpret,
-    )(lens, q, k, v)
+    )(lens, q, k.reshape(b, s_pad, hkv * dp), v.reshape(b, s_pad, hkv * dp))
+    return out.reshape(b, hq, dp)[:, :, :d]

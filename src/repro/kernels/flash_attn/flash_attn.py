@@ -7,11 +7,16 @@ the dominant (and HLO-irreducible) memory-roofline term of every train/
 prefill cell (EXPERIMENTS §Perf C4): scores and probabilities live only in
 VMEM tiles; HBM sees q, k, v and o exactly once.
 
-Grid: (B, Hkv, S_q/bq, S_kv/bk) — the KV axis innermost so the online-
+Grid: (B, Hq, S_q/bq, S_kv/bk) — the KV axis innermost so the online-
 softmax running state (m, l, acc) persists in VMEM scratch across KV
-blocks of one query tile.  Both the causal upper triangle and the KV tail
-past ``kv_len`` are skipped at runtime via ``pl.when`` on the SMEM scalars
-(no MXU work issued for dead blocks).
+blocks of one query tile.  Heads are lane blocks: q/o are viewed as
+(B, S, Hq*D) and k/v as (B, S, Hkv*D) (free reshapes), and query head h
+reads lane block h // (Hq/Hkv) of k/v, so no head axis sits in the last
+two block dims.  A head dim that is not a multiple of 128 lanes is
+zero-padded up to one (the scores and outputs are unchanged).  Both the
+causal upper triangle and the KV tail past ``kv_len`` are skipped at
+runtime via ``pl.when`` on the SMEM scalars (no MXU work issued for dead
+blocks).
 
 Runtime operands: ``kv_len`` — a (B,) int32 valid-prefix length per batch
 row — and ``q_offset`` — the absolute position of query row 0 — ride in as
@@ -24,11 +29,10 @@ Sq != Skv is supported; under ``causal=True`` query row i attends to key
 positions j <= q_offset + i (cache prefill: q_offset = idx,
 kv_len = idx + Sq).  Sq/Skv are padded to tile multiples internally.
 
-Interpret policy (`kernels.attn_common`): ``interpret=None`` compiles on a
-TPU backend and falls back to interpret mode elsewhere (CPU CI);
-``REPRO_ATTN_INTERPRET=0|1`` overrides both.  In interpret mode the default
-tile is the whole (padded) sequence — the interpreter pays per grid step,
-not per byte of VMEM — while the compiled default is 256 x 256.
+Interpret policy (`kernels.common`): ``interpret=None`` compiles on a TPU
+backend and runs in the Pallas interpreter elsewhere (CPU tests); both
+modes use the same tiles, 256 x 256 or the sequence rounded up to 16 rows
+when it is shorter.
 
 Public surface
 --------------
@@ -49,17 +53,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.attn_common import NEG_INF, SCALAR_SPACE, default_interpret
+from repro.kernels.common import (LANES, NEG_INF, SCALAR_SPACE,
+                                  resolve_interpret, round_up)
 
 
 def _kernel(lens_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref,
-            *, bq: int, bk: int, n_kb: int, causal: bool, g: int):
+            *, bq: int, bk: int, n_kb: int, causal: bool, scale: float):
     bi = pl.program_id(0)
     qb = pl.program_id(2)
     kb = pl.program_id(3)
-    kv_len = lens_ref[bi]                       # runtime scalar operands
-    q_off = off_ref[0]
+    kv_len = lens_ref[0, bi]                    # runtime scalar operands
+    q_off = off_ref[0, 0]
 
     @pl.when(kb == 0)
     def _init():
@@ -75,41 +80,36 @@ def _kernel(lens_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :, :].astype(jnp.float32)    # (bq, g, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # (bk, D)
-        d = q.shape[-1]
-        sc = jnp.einsum("qgd,kd->gqk", q * (d ** -0.5), k)   # (g, bq, bk)
-        kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
+        q = q_ref[0].astype(jnp.float32) * scale         # (bq, D)
+        k = k_ref[0].astype(jnp.float32)                 # (bk, D)
+        v = v_ref[0].astype(jnp.float32)                 # (bk, D)
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         mask = kpos < kv_len
         if causal:
             qpos = q_off + qb * bq + jax.lax.broadcasted_iota(
-                jnp.int32, sc.shape, 1)
+                jnp.int32, sc.shape, 0)
             mask = mask & (qpos >= kpos)
         sc = jnp.where(mask, sc, NEG_INF)
-        m_prev = m_ref[...]                              # (g, bq)
+        m_prev = m_ref[...]                              # (bq, 1)
         l_prev = l_ref[...]
-        acc_prev = acc_ref[...]                          # (g, bq, D)
-        m_new = jnp.maximum(m_prev, sc.max(-1))
+        m_new = jnp.maximum(m_prev, sc.max(-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # NEG_INF - NEG_INF == 0 in f32, so a fully-masked row would get
         # exp(0) == 1 garbage: zero masked entries explicitly.
-        p = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
-        l_new = l_prev * alpha + p.sum(-1)
-        acc_new = acc_prev * alpha[..., None] \
-            + jnp.einsum("gqk,kd->gqd", p, v)
+        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+        l_ref[...] = l_prev * alpha + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
-        l_ref[...] = l_new
-        acc_ref[...] = acc_new
 
     # finalize reads the REFS (not compute-locals): the last KV block may
     # have been skipped as dead, so its locals never exist.
     @pl.when(kb == n_kb - 1)
     def _finalize():
-        acc = acc_ref[...]
-        l = l_ref[...]
-        out = acc / jnp.maximum(l, 1e-30)[..., None]     # (g, bq, D)
-        o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)   # (bq, D)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def flash_attn_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -123,20 +123,13 @@ def flash_attn_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``kv_len`` (B,) int32 valid-prefix lengths (default: full Skv) and
     ``q_offset`` scalar int32 absolute position of query row 0 (default 0)
     are RUNTIME operands — sweeping them reuses one compiled program.
-    ``interpret=None`` resolves via ``default_interpret()`` here, OUTSIDE
-    the jit, so the env override is honoured on every call."""
+    ``interpret`` resolves through `kernels.common.resolve_interpret` here,
+    OUTSIDE the jit."""
     b, sq, hq, d = q.shape
     skv = k.shape[1]
-    if interpret is None:
-        interpret = default_interpret()
-    # interpret mode pays per grid step, not per byte of VMEM: default to
-    # whole-sequence tiles (grid = B x Hkv); compiled mode to 256 x 256
-    if bq is None:
-        bq = sq if interpret else 256
-    if bk is None:
-        bk = skv if interpret else 256
-    bq = min(bq, sq)
-    bk = min(bk, skv)
+    interpret = resolve_interpret(interpret)
+    bq = min(bq or 256, round_up(sq, 16))
+    bk = min(bk or 256, round_up(skv, 16))
     if kv_len is None:
         kv_len = jnp.full((b,), skv, jnp.int32)
     if q_offset is None:
@@ -155,43 +148,40 @@ def _flash_attn_call(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     skv, hkv = k.shape[1], k.shape[2]
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
-    n_qb = -(-sq // bq)
-    n_kb = -(-skv // bk)
-    sq_p, skv_p = n_qb * bq, n_kb * bk
-    if sq_p != sq:
-        q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
-    if skv_p != skv:
-        k = jnp.pad(k, ((0, 0), (0, skv_p - skv), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, skv_p - skv), (0, 0), (0, 0)))
+    dp = round_up(d, LANES)
+    sq_p, skv_p = round_up(sq, bq), round_up(skv, bk)
+    n_qb, n_kb = sq_p // bq, skv_p // bk
+    q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, dp - d)))
+    k = jnp.pad(k, ((0, 0), (0, skv_p - skv), (0, 0), (0, dp - d)))
+    v = jnp.pad(v, ((0, 0), (0, skv_p - skv), (0, 0), (0, dp - d)))
     # clamp to the true KV length: padded tail positions are never valid
-    lens = jnp.minimum(jnp.asarray(kv_len, jnp.int32).reshape(b), skv)
-    off = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    # regroup q as (B, Sq, Hkv, g, D) so one grid step owns one kv head
-    qg = q.reshape(b, sq_p, hkv, g, d)
+    # 2-D scalar operands stay whole SMEM blocks under vmap
+    lens = jnp.minimum(jnp.asarray(kv_len, jnp.int32).reshape(1, b), skv)
+    off = jnp.asarray(q_offset, jnp.int32).reshape(1, 1)
 
     kern = functools.partial(_kernel, bq=bq, bk=bk, n_kb=n_kb,
-                             causal=causal, g=g)
+                             causal=causal, scale=d ** -0.5)
+    kv_spec = pl.BlockSpec((1, bk, dp),
+                           lambda bi, hi, qi, ki: (bi, ki, hi // g))
     out = pl.pallas_call(
         kern,
-        grid=(b, hkv, n_qb, n_kb),
+        grid=(b, hq, n_qb, n_kb),
         in_specs=[
             pl.BlockSpec(memory_space=SCALAR_SPACE),
             pl.BlockSpec(memory_space=SCALAR_SPACE),
-            pl.BlockSpec((1, bq, 1, g, d),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi, 0)),
+            pl.BlockSpec((1, bq, dp), lambda bi, hi, qi, ki: (bi, qi, hi)),
+            kv_spec,
+            kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, bq, g, d),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, hq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, dp),
+                               lambda bi, hi, qi, ki: (bi, qi, hi)),
+        out_shape=jax.ShapeDtypeStruct((b, sq_p, hq * dp), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((g, bq), jnp.float32),
-            pltpu.VMEM((g, bq), jnp.float32),
-            pltpu.VMEM((g, bq, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, dp), jnp.float32),
         ],
         interpret=interpret,
-    )(lens, off, qg, k, v)
-    return out[:, :sq]
+    )(lens, off, q.reshape(b, sq_p, hq * dp), k.reshape(b, skv_p, hkv * dp),
+      v.reshape(b, skv_p, hkv * dp))
+    return out.reshape(b, sq_p, hq, dp)[:, :sq, :, :d]

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.attn_common import NEG_INF
+from repro.kernels.common import NEG_INF
 from repro.kernels.flash_attn.flash_attn import flash_attn_pallas
 
 

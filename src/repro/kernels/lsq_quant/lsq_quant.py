@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import resolve_interpret
+
 
 def _kernel(x_ref, s_ref, o_ref, *, qn: float, qp: float):
     s = jnp.maximum(s_ref[0], 1e-8)
@@ -17,10 +19,17 @@ def _kernel(x_ref, s_ref, o_ref, *, qn: float, qp: float):
     o_ref[...] = jnp.clip(jnp.round(x / s), qn, qp) * s
 
 
+def lsq_quant_pallas(x: jnp.ndarray, s: jnp.ndarray, *, qn: float, qp: float,
+                     bm: int = 1024,
+                     interpret: bool | None = None) -> jnp.ndarray:
+    return _lsq_quant_call(x, s, qn=qn, qp=qp, bm=bm,
+                           interpret=resolve_interpret(interpret))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("qn", "qp", "bm", "interpret"))
-def lsq_quant_pallas(x: jnp.ndarray, s: jnp.ndarray, *, qn: float, qp: float,
-                     bm: int = 1024, interpret: bool = True) -> jnp.ndarray:
+def _lsq_quant_call(x: jnp.ndarray, s: jnp.ndarray, *, qn: float, qp: float,
+                    bm: int, interpret: bool) -> jnp.ndarray:
     shape = x.shape
     flat = x.reshape(-1)
     n = flat.shape[0]

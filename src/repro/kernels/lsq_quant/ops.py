@@ -9,7 +9,7 @@ from repro.quant.lsq import qrange
 
 
 def lsq_quant(x: jnp.ndarray, s: jnp.ndarray, bits: int, signed: bool,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool | None = None) -> jnp.ndarray:
     qn, qp = qrange(bits, signed)
     return lsq_quant_pallas(x, s, qn=float(qn), qp=float(qp),
                             interpret=interpret)

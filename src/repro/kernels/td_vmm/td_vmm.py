@@ -22,15 +22,18 @@ program serves the whole noise-tolerance sweep with traced sigma under
 vmap, with zero recompiles.  The per-bit plane loop is a
 ``lax.fori_loop``, keeping trace size constant up to bits_a = 8.
 
-Grid: (M/bm, N/bn, K/n_chain) — K innermost so the output tile is revisited
-and accumulated in place.
+Grid: (M/bm, N/bn, n_seg) — segments innermost so the output tile is
+revisited and accumulated in place.  A segment's stride in memory is
+n_chain rounded up to the 128-lane width (576 -> 640): the wrapper lays
+each segment out at that stride and the kernel masks the pad lanes exactly
+like the contraction tail past ``k_true``, so every block is lane-aligned
+while the noise, the tail scale sqrt(live / n_chain) and the result are
+those of the unpadded chain.
 
-Interpret policy: ``interpret=None`` (the default) compiles on a TPU
-backend and falls back to interpret mode elsewhere (CPU CI); the env var
-``REPRO_TD_VMM_INTERPRET=0|1`` overrides both.  In interpret mode the
-default tile is the whole (padded) output — the interpreter pays per grid
-step, not per byte of VMEM — while the compiled default is the MXU-shaped
-128 x 128.
+Interpret policy (`kernels.common`): ``interpret=None`` compiles on a TPU
+backend and runs in the Pallas interpreter elsewhere (CPU tests); both
+modes use the same tiling: ``bm`` = M rounded up to 8 rows, at most 128,
+and ``bn`` = N rounded up to 128 lanes, at most 512.
 
 Public surface (API reference)
 ------------------------------
@@ -55,8 +58,6 @@ k_true=None, bm=None, bn=None, interpret=None) -> (M, N) float32``
     equal to the jnp simulator oracle at sigma=0, q<=1
     (`ref.td_vmm_signed_ref`, tests/test_td_vmm_engine.py).
 
-``default_interpret() -> bool`` — the env/backend interpret policy above.
-
 Consumers: `tdsim.td_linear.td_matmul` routes EVERY ``mode == "td"``
 matmul here (custom_vjp STE: Pallas forward, fake-quant backward);
 `kernels.td_vmm.ops` holds the jit wrapper, `kernels.td_vmm.ref` the
@@ -68,17 +69,13 @@ engine solved.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable without a TPU; guard for exotic builds anyway
-    from jax.experimental.pallas import tpu as pltpu
-    _SCALAR_SPACE = pltpu.SMEM
-except Exception:  # pragma: no cover
-    _SCALAR_SPACE = pl.ANY
+from repro.kernels.common import (LANES, SCALAR_SPACE, resolve_interpret,
+                                  round_up)
 
 # GOLDEN salts the second Box-Muller hash stream and the seed derivation
 # (ref.derive_seed) so one uint32 seed yields independent streams.
@@ -95,22 +92,18 @@ def _hash32(x):
 
 
 def _uniform(bits):
-    return (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24)) \
-        + (0.5 / (1 << 24))
-
-
-def default_interpret() -> bool:
-    """Interpret policy: env override, else compile iff a TPU backend is up."""
-    env = os.environ.get("REPRO_TD_VMM_INTERPRET")
-    if env is not None:
-        return env.strip().lower() in ("1", "true", "yes")
-    return jax.default_backend() != "tpu"
+    # bits >> 8 < 2^24, so the int32 view converts to the same float the
+    # oracle's uint32 -> float32 cast gives (Mosaic has no unsigned cast)
+    top = jax.lax.bitcast_convert_type(bits >> 8, jnp.int32)
+    return top.astype(jnp.float32) * (1.0 / (1 << 24)) + (0.5 / (1 << 24))
 
 
 def _td_vmm_kernel(par_ref, seed_ref, x_ref, w_ref, o_ref, *, bits_a: int,
-                   bits_w: int, n_chain: int, n_seg: int, m_total: int,
-                   n_total: int, k_true: int, bm: int, bn: int):
-    """One (bm, bn) output tile, one chain segment (k-step), signed codes."""
+                   bits_w: int, n_chain: int, stride: int, n_seg: int,
+                   m_total: int, n_total: int, k_true: int, bm: int,
+                   bn: int):
+    """One (bm, bn) output tile, one chain segment (k-step), signed codes.
+    The segment arrives as ``stride`` lanes, the first ``n_chain`` real."""
     seg = pl.program_id(2)
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -122,41 +115,43 @@ def _td_vmm_kernel(par_ref, seed_ref, x_ref, w_ref, o_ref, *, bits_a: int,
         # the +K*ox*ow term of the offset-correction identity
         o_ref[...] = jnp.full(o_ref.shape, jnp.float32(k_true * ox * ow))
 
-    sigma = par_ref[0]                          # runtime scalar operands
-    q = jnp.maximum(par_ref[1], 1.0)
-    seed = seed_ref[0]
+    sigma = par_ref[0, 0]                       # runtime scalar operands
+    q = jnp.maximum(par_ref[0, 1], 1.0)
+    seed = seed_ref[0, 0]
 
-    # offset-encode in tile; contraction positions past k_true encode 0
-    # (zero offset weight) so padding contributes nothing to dot or side-sums
-    kpos = seg * n_chain + jax.lax.broadcasted_iota(jnp.int32,
-                                                    (1, n_chain), 1)
-    live = kpos < k_true
-    x = jnp.where(live, x_ref[...] + ox, 0)                  # (bm, n_chain)
-    w = jnp.where(live.reshape(n_chain, 1),
-                  (w_ref[...] + ow).astype(jnp.float32), 0.0)  # (n_chain, bn)
+    # offset-encode in tile; stride pad lanes and contraction positions
+    # past k_true encode 0 (zero offset weight) so they contribute nothing
+    # to the dot or the side-sums
+    def live(shape, dim):
+        c = jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+        return (c < n_chain) & (seg * n_chain + c < k_true)
+
+    x = jnp.where(live((1, stride), 1), x_ref[...] + ox, 0)  # (bm, stride)
+    w = jnp.where(live((stride, 1), 0),
+                  (w_ref[...] + ow).astype(jnp.float32), 0.0)  # (stride, bn)
 
     # tail segment holds k_true - (n_seg-1)*n_chain live cells: Eq. 5's
     # sigma ~ sqrt(N) scaling, identical to td_matmul_int / the ref oracle
-    n_live = jnp.minimum(
-        jnp.float32(n_chain),
-        jnp.maximum(jnp.float32(k_true) - seg.astype(jnp.float32) * n_chain,
-                    1.0))
-    sig_seg = sigma * jnp.sqrt(n_live / jnp.float32(n_chain))
+    n_live = jnp.clip(k_true - seg * n_chain, 1, n_chain)
+    n_live = jnp.full((1, 1), n_live, jnp.int32).astype(jnp.float32)
+    sig_seg = sigma * jnp.sqrt(n_live / jnp.float32(n_chain))   # (1, 1)
 
     # noise indices use the TRUE (m, n): identical to the ref oracle; padded
     # rows/cols may collide but are sliced away by the wrapper.
-    row = (jax.lax.broadcasted_iota(jnp.uint32, (bm, bn), 0)
-           + jnp.uint32(bm) * i.astype(jnp.uint32))
-    col = (jax.lax.broadcasted_iota(jnp.uint32, (bm, bn), 1)
-           + jnp.uint32(bn) * j.astype(jnp.uint32))
+    row = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0) + bm * i
+    col = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) + bn * j
+    cell = (jax.lax.bitcast_convert_type(row, jnp.uint32)
+            * jnp.uint32(n_total)
+            + jax.lax.bitcast_convert_type(col, jnp.uint32))
 
     def plane_step(b, acc):
-        bu = b.astype(jnp.uint32)
         plane = ((x >> b) & 1).astype(jnp.float32)
         partial = jax.lax.dot(plane, w,
                               preferred_element_type=jnp.float32)
-        idx = ((bu * jnp.uint32(n_seg) + seg.astype(jnp.uint32))
-               * jnp.uint32(m_total) + row) * jnp.uint32(n_total) + col
+        base = jax.lax.bitcast_convert_type(
+            jnp.full((1, 1), (b * n_seg + seg) * m_total, jnp.int32),
+            jnp.uint32)
+        idx = base * jnp.uint32(n_total) + cell
         h1 = _hash32(idx ^ seed)
         h2 = _hash32(idx ^ seed ^ jnp.uint32(GOLDEN))
         z = jnp.sqrt(-2.0 * jnp.log(_uniform(h1))) \
@@ -185,21 +180,17 @@ def td_vmm_pallas(x_int: jnp.ndarray, w_int: jnp.ndarray,
     anything — positions >= k_true are masked in-kernel).  ``params`` is the
     (2,) float32 runtime scalar operand [sigma_chain, tdc_q]; ``seed`` a
     uint32 scalar (see ref.derive_seed).  M, N are padded up to tile
-    multiples internally.  ``interpret=None`` resolves via
-    ``default_interpret()`` here, OUTSIDE the jit, so the env override is
-    honoured on every call (resolved values are the jit cache key)."""
+    multiples internally.  ``interpret`` resolves through
+    `kernels.common.resolve_interpret` here, OUTSIDE the jit."""
     m = x_int.shape[0]
     n = w_int.shape[1]
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     if k_true is None:
         k_true = x_int.shape[1]
-    # interpret mode pays per grid step, not per byte of VMEM: default to
-    # whole-output tiles (grid = segments only); compiled mode to MXU tiles
     if bm is None:
-        bm = m if interpret else 128
+        bm = min(128, round_up(m, 8))
     if bn is None:
-        bn = n if interpret else 128
+        bn = min(512, round_up(n, LANES))
     return _td_vmm_call(x_int, w_int, params, seed, bits_a=bits_a,
                         bits_w=bits_w, n_chain=n_chain, k_true=k_true,
                         bm=bm, bn=bn, interpret=interpret)
@@ -216,24 +207,33 @@ def _td_vmm_call(x_int: jnp.ndarray, w_int: jnp.ndarray,
     n = w_int.shape[1]
     assert k % n_chain == 0, "pad K to a multiple of n_chain first"
     n_seg = k // n_chain
-    m_pad = -(-m // bm) * bm
-    n_pad = -(-n // bn) * bn
-    x_p = jnp.pad(x_int, ((0, m_pad - m), (0, 0))).astype(jnp.int32)
-    w_p = jnp.pad(w_int, ((0, 0), (0, n_pad - n))).astype(jnp.int32)
-    params = jnp.asarray(params, jnp.float32).reshape(2)
-    seed_arr = jnp.asarray(seed, jnp.uint32).reshape(1)
+    stride = round_up(n_chain, LANES)
+    m_pad = round_up(m, bm)
+    n_pad = round_up(n, bn)
+    # lay each segment out at the lane-aligned stride (pad lanes masked)
+    x_p = jnp.pad(x_int.reshape(m, n_seg, n_chain),
+                  ((0, m_pad - m), (0, 0), (0, stride - n_chain)))
+    w_p = jnp.pad(w_int.reshape(n_seg, n_chain, n),
+                  ((0, 0), (0, stride - n_chain), (0, n_pad - n)))
+    x_p = x_p.reshape(m_pad, n_seg * stride).astype(jnp.int32)
+    w_p = w_p.reshape(n_seg * stride, n_pad).astype(jnp.int32)
+    # 2-D scalar operands: under vmap (the noise-tolerance sweep batches
+    # sigma and seed) each call still reads one whole (1, n) SMEM block
+    params = jnp.asarray(params, jnp.float32).reshape(1, 2)
+    seed_arr = jnp.asarray(seed, jnp.uint32).reshape(1, 1)
 
     kern = functools.partial(
         _td_vmm_kernel, bits_a=bits_a, bits_w=bits_w, n_chain=n_chain,
-        n_seg=n_seg, m_total=m, n_total=n, k_true=k_true, bm=bm, bn=bn)
+        stride=stride, n_seg=n_seg, m_total=m, n_total=n, k_true=k_true,
+        bm=bm, bn=bn)
     out = pl.pallas_call(
         kern,
         grid=(m_pad // bm, n_pad // bn, n_seg),
         in_specs=[
-            pl.BlockSpec(memory_space=_SCALAR_SPACE),
-            pl.BlockSpec(memory_space=_SCALAR_SPACE),
-            pl.BlockSpec((bm, n_chain), lambda i, j, s: (i, s)),
-            pl.BlockSpec((n_chain, bn), lambda i, j, s: (s, j)),
+            pl.BlockSpec(memory_space=SCALAR_SPACE),
+            pl.BlockSpec(memory_space=SCALAR_SPACE),
+            pl.BlockSpec((bm, stride), lambda i, j, s: (i, s)),
+            pl.BlockSpec((stride, bn), lambda i, j, s: (s, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32),

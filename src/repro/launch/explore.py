@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.core import explorer as explorer_mod
 from repro.core import scenario as scenario_mod
+from repro.launch.compile_cache import enable_compile_cache
 
 DEFAULT_PORT = int(os.environ.get("REPRO_EXPLORER_PORT", "7749"))
 
@@ -350,6 +351,7 @@ def main(argv=None) -> None:
                     metavar="SCENARIO[:CORNER]",
                     help="sweep these before accepting queries (repeatable)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cache_dir = args.cache_dir or os.environ.get("REPRO_EXPLORER_CACHE_DIR")
     svc = explorer_mod.ExplorerService(cache_dir=cache_dir or None)

@@ -14,12 +14,8 @@ import jax
 
 
 def _make_mesh(shape: tuple, axes: tuple):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        # older jax: no explicit-sharding axis types; Auto is the default
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,11 +30,9 @@ def make_mesh(shape: tuple, axes: tuple):
 
 
 def activate_mesh(mesh):
-    """Context manager making `mesh` the ambient mesh: jax.set_mesh when
-    available (also feeds get_abstract_mesh), else the plain Mesh context
-    of older jax (NamedShardings carry the mesh regardless)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """Context manager making `mesh` the ambient mesh (`jax.set_mesh`,
+    which also feeds `get_abstract_mesh`)."""
+    return jax.set_mesh(mesh)
 
 
 def dp_axes(mesh) -> tuple:

@@ -15,14 +15,16 @@ asks for:
     other slots keep decoding.  The flash-decode kernel's runtime
     ``kv_len`` SMEM operand masks every slot to its own valid prefix, so
     ANY mix of fill levels reuses one compiled program — zero recompiles.
-  * **Block KV slots sized off the roofline model** —
+  * **Block KV slots sized off the device** —
     `roofline.model.plan_kv_cache` rounds slots to block granularity and
-    caps capacity against the chip HBM budget.
+    caps capacity against the device's own memory limit less the
+    resident weights (held in the compute dtype, bf16).
   * **Per-request TD energy/latency telemetry** —
     `energy_meter.RequestMeter` attributes J/token to each request
     (prefill + decode tokens at the policy's operating point), and
     per-token wall-clock timestamps give per-request p50/p99 ms/token.
-  * **Fault tolerance** — the loop runs under `ft.run_with_retries` with
+  * **Fault tolerance** — with a fault source (`inject` or a
+    `ft.FaultSchedule`) the loop runs under `ft.run_with_retries`, with
     the `ft.StepWatchdog` timing every step; a mid-stream `Preemption`
     drains in-flight requests back onto the queue as continuations
     (prompt + tokens generated so far) instead of killing the run, so no
@@ -158,21 +160,25 @@ class ContinuousBatchingEngine:
         # compiled programs, only the scheduling policy differs
         self.continuous = continuous
 
-        # block KV slots sized off the roofline HBM model: round the slot
-        # to blocks, cap capacity at what the budget admits
-        self.kv_plan = roofline_model.plan_kv_cache(
-            cfg, capacity, s_cache, block=kv_block)
-        self.capacity = min(capacity, max(1, self.kv_plan.max_slots))
-        self.s_cache = self.kv_plan.s_cache
-        self.prompt_pad = min(prompt_pad or self.s_cache, self.s_cache)
-
         self.pol = common.resolve_arch_policy(arch)
         api = get_api(cfg)
         # independent key streams per consumer (params here; callers draw
-        # prompt keys from their own split — see serve.run)
+        # prompt keys from their own split — see serve.run).  Serving holds
+        # the weights in the compute dtype, as deployments do.
         if params is None:
-            params = api["init"](jax.random.key(seed), cfg, self.pol)
+            params = api["init"](jax.random.key(seed), cfg, self.pol,
+                                 steps_lib.DTYPES[arch.train.compute_dtype])
         self.params = params
+
+        # block KV slots: round the slot to blocks, cap capacity at what the
+        # device's own memory limit admits once the weights are resident
+        self.kv_plan = roofline_model.plan_kv_cache(
+            cfg, capacity, s_cache, block=kv_block,
+            weight_bytes=roofline_model.tree_bytes(params),
+            hbm_bytes=roofline_model.device_bytes_limit())
+        self.capacity = min(capacity, max(1, self.kv_plan.max_slots))
+        self.s_cache = self.kv_plan.s_cache
+        self.prompt_pad = min(prompt_pad or self.s_cache, self.s_cache)
 
         self._prefill = jax.jit(
             steps_lib.build_ragged_prefill_step(arch, self.prompt_pad))
@@ -560,10 +566,13 @@ class ContinuousBatchingEngine:
     def run(self, requests=None, retry_policy: ft.RetryPolicy | None = None,
             inject=None, schedule: "ft.FaultSchedule | None" = None,
             trace: "ft.TrafficTrace | None" = None) -> dict:
-        """Drive the loop to completion under retry protection.
+        """Drive the loop to completion.
 
         `inject(step_index)` (tests/bench) may raise `ft.Preemption` to
-        simulate node loss; the engine drains and re-admits.  `schedule`
+        simulate node loss; the engine drains and re-admits under
+        `retry_policy`.  Retries apply only to such injected faults (an
+        `inject` or a `schedule`): without one, the first failure
+        propagates and fails the run.  `schedule`
         is a deterministic `ft.FaultSchedule` consumed fire-once per step:
         preemptions drain-and-retry, stalls sleep (the watchdog flags
         them), drift events scale the measured activity, explorer outages
@@ -587,8 +596,11 @@ class ContinuousBatchingEngine:
                 if not self.step():
                     return True
 
-        ft.run_with_retries(body, policy=retry_policy,
-                            on_restart=lambda n, e: self.drain())
+        if schedule is None and inject is None:
+            body()      # no fault source: a failure fails the run
+        else:
+            ft.run_with_retries(body, policy=retry_policy,
+                                on_restart=lambda n, e: self.drain())
         while self._staged is not None:
             # a rebuild still in flight when the queue drained: land it (or
             # surface its error) so the summary reflects the final policy;

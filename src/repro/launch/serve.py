@@ -24,6 +24,7 @@ import repro.configs as cfgs
 from repro.configs.base import ShapeCfg
 from repro.launch import steps as steps_lib
 from repro.launch import td_cli
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.scheduler import ContinuousBatchingEngine, Request
 from repro.models import common, get_api, matmul_shapes
 from repro.tdsim import energy_meter
@@ -36,7 +37,9 @@ def run(arch, batch: int, prompt_len: int, gen: int, seed: int = 0):
     # one independent key stream per consumer: reusing a single key would
     # correlate param init, prompt sampling and frontend embeds
     k_params, k_prompts, k_embeds = jax.random.split(jax.random.key(seed), 3)
-    params = api["init"](k_params, cfg, pol)
+    # serving holds the weights in the compute dtype, as deployments do
+    params = api["init"](k_params, cfg, pol,
+                         steps_lib.DTYPES[arch.train.compute_dtype])
     s_cache = prompt_len + gen
 
     shape = ShapeCfg("serve", s_cache, batch, "decode")
@@ -199,6 +202,7 @@ def main():
     td_cli.add_td_attn_arg(ap)
     td_cli.add_scenario_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     arch = cfgs.get_smoke(args.arch) if args.smoke else cfgs.get(args.arch)
     arch = td_cli.apply_td_args(arch, args.td, args.td_per_layer,
                                 args.scenario, args.corner,

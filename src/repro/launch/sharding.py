@@ -110,34 +110,6 @@ def batch_spec(mesh, batch_size: int, rank: int) -> P:
     return P(lead, *([None] * (rank - 1)))
 
 
-def probe_spec(mesh, n_probes: int, rank: int, axis: int = 0) -> P:
-    """Shard probe axis `axis` of a rank-`rank` eval batch over (pod, data).
-
-    The noise-tolerance sweep's flat probe axis (or, when chunked, the
-    within-chunk axis) is embarrassingly parallel — each probe is an
-    independent model eval — so it rides the data axis like any batch dim.
-    Falls back to replication when the axis does not divide (correct, just
-    unsharded), keeping every (probe-count x mesh) combination runnable.
-    """
-    dp = dp_axes(mesh)
-    dp_n = 1
-    for a in dp:
-        dp_n *= mesh.shape[a]
-    parts: list = [None] * rank
-    if n_probes % dp_n == 0:
-        parts[axis] = dp if len(dp) > 1 else dp[0]
-    return P(*parts)
-
-
-def shard_probes(mesh, arrays, axis: int = 0):
-    """NamedSharding-place each array's probe axis over the mesh data axis
-    (`probe_spec`); arrays is a tuple pytree of same-probe-count arrays."""
-    def place(a):
-        spec = probe_spec(mesh, a.shape[axis], a.ndim, axis)
-        return jax.device_put(a, NamedSharding(mesh, spec))
-    return jax.tree_util.tree_map(place, arrays)
-
-
 def cache_specs(state_shapes, mesh) -> object:
     """PartitionSpecs for a decode-state pytree (KV caches, SSM states).
 

@@ -24,6 +24,7 @@ from repro.data.pipeline import PrefetchLoader
 from repro.data.synthetic import DataCfg, SyntheticStream
 from repro import ft
 from repro.launch import td_cli
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import steps as steps_lib
 from repro.models import get_api
 from repro.models import common
@@ -160,6 +161,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = cfgs.get_smoke(args.arch) if args.smoke else cfgs.get(args.arch)
     arch = td_cli.apply_td_args(arch, args.td, args.td_per_layer,

@@ -181,21 +181,21 @@ def replace_td_layers(pol, solved):
 # ---------------------------------------------------------------------------
 # Sharding constraints (no-ops outside a mesh context)
 # ---------------------------------------------------------------------------
-def _abstract_mesh():
-    """jax.sharding.get_abstract_mesh, tolerating jax versions without it
-    (no queryable mesh -> behave as if none is active)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
+def _auto_axes(env) -> set:
+    """Axis names of the ambient mesh that the compiler partitions (inside
+    a `shard_map` body the mesh axes are Manual and take no constraint)."""
+    return {n for n, t in zip(env.axis_names, env.axis_types)
+            if t == jax.sharding.AxisType.Auto}
 
 
 def maybe_constrain(x: jnp.ndarray, *axes) -> jnp.ndarray:
     """with_sharding_constraint(x, P(*axes)) if a global mesh providing all
     referenced axis names is active; otherwise identity.  Lets model code
     carry distribution hints without coupling tests to a mesh."""
-    env = _abstract_mesh()
-    if env is None or env.empty:
+    env = jax.sharding.get_abstract_mesh()
+    names = _auto_axes(env)
+    if not names:
         return x
-    names = set(env.axis_names)
 
     def ok(a):
         if a is None:
@@ -222,10 +222,10 @@ def maybe_constrain(x: jnp.ndarray, *axes) -> jnp.ndarray:
 
 
 def batch_sharding_axes(env=None):
-    env = env or _abstract_mesh()
-    if env is None or env.empty:
+    names = _auto_axes(env or jax.sharding.get_abstract_mesh())
+    if "data" not in names:
         return None
-    return ("pod", "data") if "pod" in env.axis_names else "data"
+    return ("pod", "data") if "pod" in names else "data"
 
 
 # ---------------------------------------------------------------------------

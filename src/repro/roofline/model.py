@@ -15,9 +15,10 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+
 PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
 HBM_BW = 819e9             # B/s per chip
-HBM_BYTES = 16e9           # HBM capacity per chip (v5e-class, 16 GB)
 LINK_BW = 50e9             # B/s per ICI link
 N_LINKS = 4                # usable links per chip on the 2D torus
 
@@ -83,18 +84,17 @@ class KVCachePlan:
     """Block-granular KV-cache sizing for the slot-batched serve engine.
 
     Slots are contiguous per request but sized in `block`-token blocks
-    against an HBM budget (a fraction of the chip's capacity net of
-    weights), so the engine's fixed capacity is a roofline-derived number
-    rather than a guess.  `max_slots` is how many slots of `s_cache`
-    tokens the budget admits; `fits` says whether the REQUESTED capacity
-    does.
+    against a memory budget (a fraction of the device's limit net of the
+    resident weights), so the engine's fixed capacity is derived rather
+    than guessed.  `max_slots` is how many slots of `s_cache` tokens the
+    budget admits; `fits` says whether the REQUESTED capacity does.
     """
     capacity: int              # requested concurrent slots
     s_cache: int               # tokens per slot, rounded up to blocks
     block: int                 # allocation granularity (tokens)
     bytes_per_slot: int
     bytes_total: int           # capacity * bytes_per_slot
-    budget_bytes: int
+    budget_bytes: int | None   # None: the device reports no limit
     max_slots: int
 
     @property
@@ -102,15 +102,31 @@ class KVCachePlan:
         return self.capacity <= self.max_slots
 
 
+def device_bytes_limit(device=None) -> int | None:
+    """The device's own memory limit (`memory_stats()["bytes_limit"]`);
+    None where the backend reports none (the CPU)."""
+    device = device or jax.local_devices()[0]
+    stats = device.memory_stats()
+    return int(stats["bytes_limit"]) if stats and "bytes_limit" in stats \
+        else None
+
+
+def tree_bytes(tree) -> int:
+    """Bytes held by a pytree of arrays (the weights' real footprint)."""
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
+
+
 def plan_kv_cache(cfg, capacity: int, s_cache: int, *, block: int = 128,
                   dtype_bytes: int = 2, weight_bytes: float = 0.0,
                   budget_frac: float = 0.9,
-                  hbm_bytes: float = HBM_BYTES) -> KVCachePlan:
-    """Size the serve engine's KV slots off the roofline HBM model.
+                  hbm_bytes: float | None = None) -> KVCachePlan:
+    """Size the serve engine's KV slots against the device memory.
 
     cfg: a ModelCfg (uses n_layers/mixer pattern/n_kv_heads/hd).  The
-    budget is `budget_frac` of (hbm_bytes - weight_bytes); per-slot bytes
-    are K+V per attention layer at `dtype_bytes` per element, with the
+    budget is `budget_frac` of (hbm_bytes - weight_bytes), with
+    `hbm_bytes` the device's limit (`device_bytes_limit`); None (no limit
+    reported) leaves the requested capacity uncapped.  Per-slot bytes are
+    K+V per attention layer at `dtype_bytes` per element, with the
     sequence rounded up to `block`-token blocks.
     """
     n_attn = sum(1 for i in range(cfg.n_layers)
@@ -118,12 +134,15 @@ def plan_kv_cache(cfg, capacity: int, s_cache: int, *, block: int = 128,
     blocks = max(1, -(-s_cache // block))
     s_pad = blocks * block
     per_slot = 2 * n_attn * s_pad * cfg.n_kv_heads * cfg.hd * dtype_bytes
-    budget = max(0.0, (hbm_bytes - weight_bytes)) * budget_frac
-    max_slots = int(budget // per_slot) if per_slot else 0
+    if hbm_bytes is None:
+        budget, max_slots = None, capacity
+    else:
+        budget = int(max(0.0, hbm_bytes - weight_bytes) * budget_frac)
+        max_slots = budget // per_slot if per_slot else 0
     return KVCachePlan(capacity=capacity, s_cache=s_pad, block=block,
                        bytes_per_slot=per_slot,
                        bytes_total=capacity * per_slot,
-                       budget_bytes=int(budget), max_slots=max_slots)
+                       budget_bytes=budget, max_slots=max_slots)
 
 
 def model_flops_train(n_params: float, tokens: float) -> float:

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.attn_common import NEG_INF
+from repro.kernels.common import NEG_INF
 from repro.kernels.td_vmm import ops as td_ops
 from repro.kernels.td_vmm import ref as td_ref
 from repro.tdsim.policy import TDPolicy

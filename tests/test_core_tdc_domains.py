@@ -2,6 +2,7 @@
 (paper §III-A, §IV, Figs. 7/9/11/12)."""
 import math
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 pytest.importorskip("hypothesis")
@@ -20,6 +21,35 @@ class TestTDC:
             grid = range(max(1, l_opt // 4), l_opt * 4 + 2)
             e_best = min(tdc.hybrid_tdc_energy(units, l) for l in grid)
             assert e_opt <= e_best * 1.0 + 1e-22
+
+    @pytest.mark.parametrize("log2_ulps", [0, 1, -1])
+    def test_bit_counts_exact_at_powers_of_two(self, monkeypatch, log2_ulps):
+        """The array paths' ceil(log2) bit counts (TDC range and LSB bits,
+        adder-tree depth) equal the float64 scalar path's at, just below
+        and just above powers of two, even when float32 log2 is off by an
+        ulp, as a TPU's is (log2(8192) = 13.000001 on a v5e)."""
+        if log2_ulps:
+            log2 = jnp.log2
+            toward = np.float32(np.inf * log2_ulps)
+            monkeypatch.setattr(jnp, "log2",
+                                lambda x: jnp.nextafter(log2(x), toward))
+        xs = []
+        for k in range(1, 20):
+            p = np.float32(2.0 ** k)
+            xs += [np.nextafter(p, np.float32(0)), p,
+                   np.nextafter(p, np.float32(np.inf))]
+        xs = np.asarray(xs, np.float32)
+        want = [math.ceil(math.log2(float(x))) for x in xs]
+        assert np.asarray(tdc.ceil_log2(xs)).tolist() == want
+        assert np.asarray(tdc.range_bits(xs)).tolist() == \
+            [tdc.range_bits(float(x)) for x in xs]
+        assert np.asarray(tdc._lsb_bits(xs)).tolist() == \
+            [tdc._lsb_bits(float(x)) for x in xs]
+        ints = np.asarray([2 ** k for k in range(1, 20)], np.float32)
+        np.testing.assert_array_equal(
+            digital._adder_bits_per_mac(ints, 4),
+            np.float32([digital._adder_bits_per_mac(int(n), 4)
+                        for n in ints]))
 
     def test_sar_energy_formula(self):
         """Eq. 10 literal check."""

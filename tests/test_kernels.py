@@ -23,6 +23,18 @@ from repro.kernels.td_vmm import ref as td_ref
 from repro.kernels.td_vmm.td_vmm import td_vmm_pallas
 
 
+def test_interpret_policy(monkeypatch):
+    """One policy for every kernel: None follows the backend, and interpret
+    mode is refused on a TPU backend."""
+    from repro.kernels import common
+    assert common.resolve_interpret(None) is common.default_interpret()
+    assert common.resolve_interpret(False) is False
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    assert common.resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="refused"):
+        common.resolve_interpret(True)
+
+
 class TestTdVmmKernel:
     @pytest.mark.parametrize("m,k,n,n_chain,bm,bn", [
         (16, 32, 16, 32, 16, 128),

@@ -230,3 +230,16 @@ def test_jit_cache_releases_dead_eval_fns():
         del fn
         gc.collect()
         assert ref() is None, "jit cache still pins a dead eval_fn"
+
+
+@pytest.mark.parametrize("probes", [1, 7, 13])
+def test_top1_accuracy_vmapped_matches_host(probes):
+    """`top1_accuracy` under vmap (how the batched sweep calls an eval_fn)
+    equals the host's first-index argmax accuracy for every probe count,
+    ties included."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    logits = jnp.round(jax.random.normal(k1, (probes, 8, 16, 32)) * 2.0)
+    labels = jax.random.randint(k2, (8, 16), 0, 32)
+    got = jax.jit(jax.vmap(lambda lo: nt.top1_accuracy(lo, labels)))(logits)
+    want = (np.asarray(logits).argmax(-1) == np.asarray(labels)).mean((1, 2))
+    np.testing.assert_array_equal(np.asarray(got), want.astype(np.float32))

@@ -137,6 +137,48 @@ class TestScheduler:
         got = {rid: list(r.generated) for rid, r in eng.done.items()}
         assert got == base_out      # greedy outputs bit-identical
 
+    def test_failure_without_fault_source_fails_run(self):
+        """Retries serve injected faults only: with no `inject` and no
+        schedule, a failure propagates instead of draining and retrying."""
+        eng = _engine(capacity=2)
+        step = eng.step
+        calls = {"n": 0}
+
+        def failing_step():
+            calls["n"] += 1
+            if calls["n"] == 2:
+                raise ft.Preemption("node lost")
+            return step()
+
+        eng.step = failing_step
+        try:
+            with pytest.raises(ft.Preemption):
+                eng.run(_reqs([(4, 3), (5, 3)]),
+                        retry_policy=ft.RetryPolicy(backoff_s=0.0))
+        finally:
+            del eng.step
+            eng.drain()                  # leave the shared engine idle
+            eng.queue.clear()
+        assert calls["n"] == 2           # no restart after the failure
+
+    def test_weights_in_compute_dtype_and_kv_plan(self):
+        """Serving holds bf16 weights; the KV plan budgets the device limit
+        less those weights' real bytes."""
+        from repro.roofline import model as roofline_model
+        eng = _engine(capacity=2)
+        assert {a.dtype for a in jax.tree_util.tree_leaves(eng.params)} \
+            == {jnp.dtype(jnp.bfloat16)}
+        cfg = eng.cfg
+        wb = roofline_model.tree_bytes(eng.params)
+        plan = roofline_model.plan_kv_cache(cfg, 8, 100, block=64,
+                                            weight_bytes=wb,
+                                            hbm_bytes=wb + 10_000_000)
+        assert plan.s_cache == 128
+        assert plan.budget_bytes == int(10_000_000 * 0.9)
+        assert plan.max_slots == plan.budget_bytes // plan.bytes_per_slot
+        free = roofline_model.plan_kv_cache(cfg, 8, 100, block=64)
+        assert free.budget_bytes is None and free.max_slots == 8
+
     def test_submit_rejects_overflowing_request(self):
         eng = _engine(capacity=1)
         with pytest.raises(ValueError, match="exceeds"):
