@@ -63,6 +63,13 @@ asks for:
     statistic fed to re-resolves, and ``load`` throttles admissions to a
     fraction of capacity.  Deterministic replay: same trace, same
     outputs.
+  * **Profiler spans** — every tick (`engine.step`: queue length and a
+    `clock` stamp), admission (`engine.admit` and its phases: prep,
+    prefill with true and padded tokens, insert, tok_write, first_token)
+    and decode call (`engine.decode` with rows and cached tokens,
+    `engine.decode_wait`, `engine.harvest`) is a `jax.profiler` span, so
+    any profiler capture shows where the host spends a tick beside the
+    device ops.
 
 Scope: decoder-family, pure-attention, token-only models (the bucketed
 prefill relies on causal masking to keep pad junk out of the prefix;
@@ -77,6 +84,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro import ft
 from repro.launch import steps as steps_lib
@@ -279,24 +287,34 @@ class ContinuousBatchingEngine:
     # admission: bucketed prefill into a free slot
     # ------------------------------------------------------------------
     def _admit(self, slot: Slot) -> None:
-        req = self.queue.popleft()
-        ctx = req.context
-        padded = np.zeros((1, self.prompt_pad), np.int32)
-        padded[0, :len(ctx)] = ctx
-        tok, pstate = self._prefill(self.params, jnp.asarray(padded),
-                                    jnp.asarray(len(ctx), jnp.int32))
-        self._state = self._insert(self._state, pstate,
-                                   jnp.asarray(slot.index, jnp.int32),
-                                   jnp.asarray(len(ctx), jnp.int32))
-        self._tok = self._tok.at[slot.index].set(tok[0])
-        slot.request = req
-        now = self.clock()
-        if req.t_admitted is None:
-            req.t_admitted = now
-        if self.meter is not None:
-            self.meter.on_prefill(req.rid, len(ctx))
-        # the prefill's argmax IS this request's next token
-        self._record_token(req, int(tok[0, 0]), now)
+        with TraceAnnotation("engine.admit", rid=self.queue[0].rid,
+                             slot=slot.index):
+            req = self.queue.popleft()
+            ctx = req.context
+            with TraceAnnotation("engine.prep"):
+                padded = np.zeros((1, self.prompt_pad), np.int32)
+                padded[0, :len(ctx)] = ctx
+                ids = jnp.asarray(padded)
+                n = jnp.asarray(len(ctx), jnp.int32)
+            with TraceAnnotation("engine.prefill", tokens=len(ctx),
+                                 padded=self.prompt_pad):
+                tok, pstate = self._prefill(self.params, ids, n)
+            with TraceAnnotation("engine.insert"):
+                self._state = self._insert(self._state, pstate,
+                                           jnp.asarray(slot.index, jnp.int32),
+                                           n)
+            with TraceAnnotation("engine.tok_write"):
+                self._tok = self._tok.at[slot.index].set(tok[0])
+            slot.request = req
+            now = self.clock()
+            if req.t_admitted is None:
+                req.t_admitted = now
+            if self.meter is not None:
+                self.meter.on_prefill(req.rid, len(ctx))
+            # the prefill's argmax IS this request's next token
+            with TraceAnnotation("engine.first_token"):
+                first = int(tok[0, 0])
+            self._record_token(req, first, now)
 
     def _record_token(self, req: Request, token: int, now: float) -> None:
         req.generated.append(token)
@@ -324,7 +342,18 @@ class ContinuousBatchingEngine:
         return [s for s in self.slots if not s.free]
 
     def step(self) -> bool:
-        """One scheduler tick.  Returns False when no work remains."""
+        """One scheduler tick.  Returns False when no work remains.
+
+        Each tick, admission and decode call is a profiler span
+        (`engine.*`, on the host thread beside the device ops): any
+        `jax.profiler` capture carries them, and with none they cost about
+        a microsecond each.  The tick's `t` stamp ties `self.clock` to the
+        trace's clock."""
+        with StepTraceAnnotation("engine.step", step_num=self.steps_run,
+                                 queue=len(self.queue), t=self.clock()):
+            return self._tick()
+
+    def _tick(self) -> bool:
         # staged supply swaps and scripted (oracle) swaps install HERE, at
         # the step boundary: the decode below is the first to see new ops
         self._poll_staged()
@@ -348,25 +377,34 @@ class ContinuousBatchingEngine:
         if not active:
             return bool(self.queue)
         self.watchdog.start(self.steps_run)
-        if self.adapt:
-            occupancy = np.zeros((self.capacity,), np.float32)
-            for s in active:
-                occupancy[s.index] = 1.0
-            self._tok, self._state, px = self._decode(
-                self.params, self._tok, self._state, self._ops,
-                jnp.asarray(occupancy))
-        else:
-            px = None
-            self._tok, self._state = self._decode(self.params, self._tok,
-                                                  self._state)
-        jax.block_until_ready(self._tok)
+        stats = {"rows": len(active)}
+        if TraceAnnotation.is_enabled():
+            stats["kv_tokens"] = sum(len(s.request.prompt)
+                                     + len(s.request.generated)
+                                     for s in active)
+        with TraceAnnotation("engine.decode", **stats):
+            if self.adapt:
+                occupancy = np.zeros((self.capacity,), np.float32)
+                for s in active:
+                    occupancy[s.index] = 1.0
+                self._tok, self._state, px = self._decode(
+                    self.params, self._tok, self._state, self._ops,
+                    jnp.asarray(occupancy))
+            else:
+                px = None
+                self._tok, self._state = self._decode(self.params, self._tok,
+                                                      self._state)
+        with TraceAnnotation("engine.decode_wait"):
+            jax.block_until_ready(self._tok)
         self.watchdog.stop()
         self.steps_run += 1
         now = self.clock()
-        toks = np.asarray(self._tok)
-        for slot in active:
-            self._record_token(slot.request, int(toks[slot.index, 0]), now)
-            self._retire_or_keep(slot)
+        with TraceAnnotation("engine.harvest"):
+            toks = np.asarray(self._tok)
+            for slot in active:
+                self._record_token(slot.request, int(toks[slot.index, 0]),
+                                   now)
+                self._retire_or_keep(slot)
         if px is not None and self._scripted is None:
             gain = self._drift_gain * (seg.activity if seg is not None
                                        else 1.0)
