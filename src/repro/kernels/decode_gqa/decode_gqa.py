@@ -14,22 +14,30 @@ are skipped entirely at runtime via ``pl.when`` (the td_vmm bar: a decode
 loop over growing fill levels reuses ONE compiled program and never touches
 dead cache blocks).
 
-Grid: (B, Hkv, S/bs).  The cache is viewed as (B, S, Hkv*D) (a free
-reshape) and KV head h is lane block h, so no head axis sits in the last
-two block dims; q and the output are viewed as (B, Hkv, g, D).  Scratch:
-m/l (g, 1), acc (g, D) — 2-D tiles, persistent across the S axis for one
-(batch row, KV head) (TPU grid is sequential over the last dim).  A head
-dim that is not a multiple of 128 lanes is zero-padded up to one.
+Grid: (B, Hkv, S/bs).  The kernel reads the cache lane-dense, as
+(B, S, Hkv*Dp) with Dp the head dim rounded up to whole 128-lane blocks,
+and KV head h is lane block h, so no head axis sits in the last two block
+dims; q and the output are viewed as (B, Hkv, g, Dp).  Scratch: m/l
+(g, 1), acc (g, Dp) — 2-D tiles, persistent across the S axis for one
+(batch row, KV head) (TPU grid is sequential over the last dim).
+
+Two cache layouts come in.  The serving engine's per-row cache is stored
+lane-dense already (`models.attention.init_cache(per_row_idx=True)`) and
+is read as it is: no pad, no reshape, and a block that divides S.  A
+(B, S, Hkv, D) cache is zero-padded to Dp and reshaped to the lane-dense
+view on every call; on a TPU that reshape is NOT free: (Hkv, D) is the
+minor tile of the 4-D layout, so it compiles to a re-tiling copy of the
+whole cache.
 
 Interpret policy (`kernels.common`): ``interpret=None`` compiles on a TPU
 backend and runs in the Pallas interpreter elsewhere (CPU tests); both
 modes use the same block, 512 or the cache rounded up to 16 rows when it
-is shorter.
+is shorter (a lane-dense cache: the largest such block that divides S).
 
 Public surface
 --------------
 ``decode_gqa_pallas(q, k, v, length, *, bs=None, interpret=None)
--> (B, Hq, D)``
+-> (B, Hq, D)``, k/v (B, S, Hkv, D) or lane-dense (B, S, Hkv*Dp)
 
 Consumers: `kernels.decode_gqa.ops.decode_attention` (the production
 wrapper `models.attention` routes s == 1 self-attention decode steps to).
@@ -94,14 +102,26 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def decode_gqa_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       length: jnp.ndarray, *, bs: int | None = None,
                       interpret: bool | None = None) -> jnp.ndarray:
-    """q (B, Hq, D); k/v (B, S, Hkv, D); length (B,) int32 RUNTIME operand.
+    """q (B, Hq, D); k/v (B, S, Hkv, D) or lane-dense (B, S, Hkv*Dp);
+    length (B,) int32 RUNTIME operand.
 
     ``interpret`` resolves through `kernels.common.resolve_interpret` here,
     OUTSIDE the jit."""
     s = k.shape[1]
     interpret = resolve_interpret(interpret)
     bs = min(bs or 512, round_up(s, 16))
+    if k.ndim == 3:
+        bs = _dividing_block(s, bs)
     return _decode_gqa_call(q, k, v, length, bs=bs, interpret=interpret)
+
+
+def _dividing_block(s: int, bs: int) -> int:
+    """The largest multiple of 16 rows up to ``bs`` that divides ``s`` (the
+    whole cache when it is that short), so a lane-dense cache is read
+    without a padded copy; ``bs`` itself where none does."""
+    if s <= bs:
+        return s
+    return next((c for c in range(bs - bs % 16, 0, -16) if s % c == 0), bs)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
@@ -109,15 +129,23 @@ def _decode_gqa_call(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      length: jnp.ndarray, *, bs: int,
                      interpret: bool) -> jnp.ndarray:
     b, hq, d = q.shape
-    s, hkv = k.shape[1], k.shape[2]
+    s = k.shape[1]
+    dp = round_up(d, LANES)
+    if k.ndim == 4:
+        # (B, S, Hkv, D): pad the head dim to whole lane blocks and view
+        # lane-dense -- a re-tiling copy of the whole cache on a TPU
+        hkv = k.shape[2]
+        k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, dp - d)))
+                .reshape(b, s, hkv * dp) for x in (k, v))
+    else:
+        hkv = k.shape[2] // dp
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
-    dp = round_up(d, LANES)
     s_pad = round_up(s, bs)
     n_blocks = s_pad // bs
+    if s_pad != s:
+        k, v = (jnp.pad(x, ((0, 0), (0, s_pad - s), (0, 0))) for x in (k, v))
     q = jnp.pad(q, ((0, 0), (0, 0), (0, dp - d))).reshape(b, hkv, g, dp)
-    k = jnp.pad(k, ((0, 0), (0, s_pad - s), (0, 0), (0, dp - d)))
-    v = jnp.pad(v, ((0, 0), (0, s_pad - s), (0, 0), (0, dp - d)))
     # clamp to the true cache length: padded tail positions are never valid
     # a 2-D scalar operand stays one whole SMEM block under vmap
     lens = jnp.minimum(jnp.asarray(length, jnp.int32).reshape(1, b), s)
@@ -139,5 +167,5 @@ def _decode_gqa_call(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((g, dp), jnp.float32),
         ],
         interpret=interpret,
-    )(lens, q, k.reshape(b, s_pad, hkv * dp), v.reshape(b, s_pad, hkv * dp))
+    )(lens, q, k, v)
     return out.reshape(b, hq, dp)[:, :, :d]

@@ -13,5 +13,6 @@ from repro.kernels.decode_gqa.decode_gqa import decode_gqa_pallas
 
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      length: jnp.ndarray) -> jnp.ndarray:
-    """q (B, Hq, D); k/v (B, S, Hkv, D); length (B,) int32 -> (B, Hq, D)."""
+    """q (B, Hq, D); k/v (B, S, Hkv, D) or lane-dense (B, S, Hkv*Dp);
+    length (B,) int32 -> (B, Hq, D)."""
     return decode_gqa_pallas(q, k, v, length)

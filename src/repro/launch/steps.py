@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeCfg
-from repro.models import common, get_api
+from repro.models import attention, common, get_api
 from repro.optim import adamw
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
@@ -172,22 +172,27 @@ def build_insert_step():
 
     Generic over the cache pytree: leaves with a leading batch dim (KV
     tensors, SSM/RWKV state) are written at the slot row — a prefill
-    cache shorter than the decode cache writes its prefix — while the
-    attention fill-index leaf (dst ``(B,)`` per-row, src scalar) is set
-    to the TRUE prompt length, which is exactly what masks the pad junk
-    the bucketed prefill wrote past it.
+    cache shorter than the decode cache writes its prefix, and a
+    (1, P, Hkv, D) prefill KV cache is laid out lane-dense first, as the
+    per-row decode cache stores it (the small source is reshaped, never
+    the destination) — while the attention fill-index leaf (``idx``: dst
+    ``(B,)`` per-row, src scalar) is set to the TRUE prompt length, which
+    is exactly what masks the pad junk the bucketed prefill wrote past it.
     """
 
     def insert_step(dst_state, src_state, slot, length):
-        def ins(dst, src):
-            if src.ndim < dst.ndim:   # scalar fill idx -> per-row idx[slot]
+        def ins(path, dst, src):
+            if path[-1] == jax.tree_util.DictKey("idx"):
+                # scalar fill idx -> per-row idx[slot]
                 return jax.lax.dynamic_update_slice(
                     dst, jnp.asarray(length, dst.dtype)[None], (slot,))
+            if src.ndim > dst.ndim:
+                src = attention.lane_dense(src)
             return jax.lax.dynamic_update_slice(
                 dst, src.astype(dst.dtype),
                 (slot,) + (0,) * (src.ndim - 1))
 
-        return jax.tree_util.tree_map(ins, dst_state, src_state)
+        return jax.tree_util.tree_map_with_path(ins, dst_state, src_state)
 
     return insert_step
 

@@ -22,7 +22,11 @@ vector.  `kv_from_valid`, when given, is a per-row valid PREFIX mask — its
 row-sums become `kv_len` (no in-repo caller passes scattered masks).
 
 Shapes: x (B, S, d); q (B, S, Hq, Dh); kv (B, S, Hkv, Dh); caches are
-(B, S_cache, Hkv, Dh) with a scalar fill index.
+(B, S_cache, Hkv, Dh) with a scalar fill index, or, with a per-row (B,)
+fill index (the continuous-batching serve engine's slots), lane-dense
+(B, S_cache, Hkv*Dp) with Dp = Dh rounded up to whole 128-lane blocks: the
+layout the decode kernel reads, so a decode step neither re-tiles nor
+copies the cache, and writes each slot's new row in place.
 """
 from __future__ import annotations
 
@@ -30,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelCfg
+from repro.kernels.common import LANES, round_up
 from repro.kernels.decode_gqa.ops import decode_attention
 from repro.kernels.flash_attn.ops import flash_attention
 from repro.models import common
@@ -68,7 +73,8 @@ def attention(params: dict, x: jnp.ndarray, cfg: ModelCfg, pol,
               attn_pols=None) -> tuple[jnp.ndarray, dict | None]:
     """Self- or cross-attention with optional KV cache.
 
-    cache: {"k": (B,Sc,Hkv,D), "v": ..., "idx": ()} — decode appends at idx.
+    cache: {"k": (B,Sc,Hkv,D), "v": ..., "idx": ()} — decode appends at
+    idx; or per-row {"k": (B,Sc,Hkv*Dp), "v": ..., "idx": (B,)}.
     kv_from: encoder output for cross-attention.  attn_pols: per-head
     TDPolicy tuple routing the contraction through the TD engine
     (None = precise fused kernels).
@@ -110,13 +116,8 @@ def attention(params: dict, x: jnp.ndarray, cfg: ModelCfg, pol,
             # each slot's own fill index; the decode kernel's runtime
             # kv_len operand masks every slot to its own valid prefix, so
             # one compiled program serves any mix of fill levels
-            def _row_update(c, u, i):
-                return jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-
-            k_all = jax.vmap(_row_update)(
-                cache["k"], k.astype(cache["k"].dtype), cache["idx"])
-            v_all = jax.vmap(_row_update)(
-                cache["v"], v.astype(cache["v"].dtype), cache["idx"])
+            k_all = write_rows(cache["k"], k[:, 0], cache["idx"])
+            v_all = write_rows(cache["v"], v[:, 0], cache["idx"])
             kv_len = jnp.minimum(cache["idx"] + s, cache["k"].shape[1])
         else:
             k_all = jax.lax.dynamic_update_slice(
@@ -165,8 +166,32 @@ def init_cache(b: int, s_cache: int, cfg: ModelCfg,
                dtype=jnp.bfloat16, per_row_idx: bool = False) -> dict:
     """KV cache.  `per_row_idx=True` gives every batch row its OWN fill
     index (B,) — the continuous-batching serve engine's ragged slots, where
-    each slot decodes against a different valid-KV prefix."""
-    idx_shape = (b,) if per_row_idx else ()
-    return {"k": jnp.zeros((b, s_cache, cfg.n_kv_heads, cfg.hd), dtype),
-            "v": jnp.zeros((b, s_cache, cfg.n_kv_heads, cfg.hd), dtype),
+    each slot decodes against a different valid-KV prefix — and stores K/V
+    lane-dense, (B, S, Hkv*Dp), as the decode kernel reads them."""
+    if per_row_idx:
+        shape = (b, s_cache, cfg.n_kv_heads * round_up(cfg.hd, LANES))
+        idx_shape = (b,)
+    else:
+        shape = (b, s_cache, cfg.n_kv_heads, cfg.hd)
+        idx_shape = ()
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             "idx": jnp.zeros(idx_shape, jnp.int32)}
+
+
+def lane_dense(x: jnp.ndarray) -> jnp.ndarray:
+    """(..., Hkv, D) -> (..., Hkv*Dp): each head zero-padded to whole
+    128-lane blocks, the per-row cache's layout."""
+    d = x.shape[-1]
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, round_up(d, LANES) - d)])
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def write_rows(cache: jnp.ndarray, new: jnp.ndarray,
+               idx: jnp.ndarray) -> jnp.ndarray:
+    """Row b of ``new`` (B, Hkv, D) into the lane-dense per-row cache
+    (B, S, Hkv*Dp) at position ``idx[b]``, clamped to the cache as
+    `lax.dynamic_update_slice` clamps.  One scatter of B rows: on a
+    donated cache XLA writes it in place, one fusion, with no loop over
+    rows and no copy of the cache."""
+    rows = lane_dense(new).astype(cache.dtype)
+    return cache.at[jnp.arange(cache.shape[0]), idx].set(rows, mode="clip")

@@ -17,6 +17,8 @@ import dataclasses
 
 import jax
 
+from repro.kernels.common import LANES, round_up
+
 PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
 HBM_BW = 819e9             # B/s per chip
 LINK_BW = 50e9             # B/s per ICI link
@@ -127,13 +129,16 @@ def plan_kv_cache(cfg, capacity: int, s_cache: int, *, block: int = 128,
     `hbm_bytes` the device's limit (`device_bytes_limit`); None (no limit
     reported) leaves the requested capacity uncapped.  Per-slot bytes are
     K+V per attention layer at `dtype_bytes` per element, with the
-    sequence rounded up to `block`-token blocks.
+    sequence rounded up to `block`-token blocks and each head to whole
+    128-lane blocks (the engine's lane-dense per-row cache,
+    `models.attention.init_cache`).
     """
     n_attn = sum(1 for i in range(cfg.n_layers)
                  if cfg.mixer_at(i) in ("attn", "shared_attn"))
     blocks = max(1, -(-s_cache // block))
     s_pad = blocks * block
-    per_slot = 2 * n_attn * s_pad * cfg.n_kv_heads * cfg.hd * dtype_bytes
+    per_slot = (2 * n_attn * s_pad * cfg.n_kv_heads * round_up(cfg.hd, LANES)
+                * dtype_bytes)
     if hbm_bytes is None:
         budget, max_slots = None, capacity
     else:

@@ -21,6 +21,7 @@ from repro.kernels.lsq_quant.lsq_quant import lsq_quant_pallas
 from repro.kernels.lsq_quant.ref import lsq_quant_ref
 from repro.kernels.td_vmm import ref as td_ref
 from repro.kernels.td_vmm.td_vmm import td_vmm_pallas
+from repro.models.attention import lane_dense, write_rows
 
 
 def test_interpret_policy(monkeypatch):
@@ -146,6 +147,65 @@ class TestDecodeGqaKernel:
         np.testing.assert_allclose(np.asarray(r, np.float32),
                                    np.asarray(p, np.float32),
                                    atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("b,hq,hkv,d,s,bs,same_blocks", [
+        (2, 8, 2, 64, 256, 64, True),      # head dim padded to a lane block
+        (3, 16, 2, 128, 1024, 512, True),  # the serving engine's layout
+        (1, 4, 4, 32, 48, 512, True),      # a cache shorter than one block
+        (2, 8, 1, 64, 300, 128, True),     # no 16-row block divides S:
+                                           # both pad S to 384
+        (2, 8, 2, 128, 96, 64, False),     # 48-row blocks; 4-D pads to 128
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_lane_dense_cache_matches_4d(self, b, hq, hkv, d, s, bs,
+                                         same_blocks, dtype):
+        """The per-row serving cache, stored lane-dense (B, S, Hkv*Dp), is
+        read as it is and gives what the (B, S, Hkv, D) path gives for the
+        same data: bit for bit where both read the same blocks (the 4-D
+        path pads S up to whole blocks, the lane-dense one takes a block
+        that divides S where a multiple of 16 rows does)."""
+        key = jax.random.PRNGKey(b * 10 + s)
+        kq, kk, kv = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (b, hq, d)).astype(dtype)
+        k = jax.random.normal(kk, (b, s, hkv, d)).astype(dtype)
+        v = jax.random.normal(kv, (b, s, hkv, d)).astype(dtype)
+        length = jnp.asarray([max(1, s - 13 * i) for i in range(b)],
+                             jnp.int32)
+        four = decode_gqa_pallas(q, k, v, length, bs=bs)
+        dense = decode_gqa_pallas(q, lane_dense(k), lane_dense(v), length,
+                                  bs=bs)
+        if same_blocks:
+            np.testing.assert_array_equal(np.asarray(dense, np.float32),
+                                          np.asarray(four, np.float32))
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(
+            np.asarray(decode_gqa_ref(q, k, v, length), np.float32),
+            np.asarray(dense, np.float32), atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("hkv,d", [(2, 128), (2, 64), (1, 32)])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_row_write_matches_vmapped_update(self, hkv, d, dtype):
+        """The per-row cache write at ragged fill indices (first and last
+        position, and one past the end, which clamps) equals the vmapped
+        `dynamic_update_slice` into the (B, S, Hkv, D) layout, bit for
+        bit, and leaves every other row as it was."""
+        s = 40
+        idx = jnp.asarray([0, 17, s - 1, s, 5], jnp.int32)
+        b = idx.shape[0]
+        kc, kn = jax.random.split(jax.random.PRNGKey(d + hkv))
+        cache = jax.random.normal(kc, (b, s, hkv, d)).astype(dtype)
+        new = jax.random.normal(kn, (b, hkv, d), jnp.float32)
+
+        def row_update(c, u, i):
+            return jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
+
+        want = lane_dense(jax.vmap(row_update)(
+            cache, new[:, None].astype(dtype), idx))
+        got = jax.jit(write_rows, donate_argnums=(0,))(
+            lane_dense(cache), new, idx)
+        assert got.shape == want.shape == (b, s, hkv * 128)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 if HAVE_HYPOTHESIS:
     class TestDecodeGqaProperties:

@@ -178,6 +178,10 @@ class TestScheduler:
         assert plan.max_slots == plan.budget_bytes // plan.bytes_per_slot
         free = roofline_model.plan_kv_cache(cfg, 8, 100, block=64)
         assert free.budget_bytes is None and free.max_slots == 8
+        # the plan counts the cache as the engine stores it (lane-dense)
+        kv = [c[n] for c in eng._state["layers"] for n in ("k", "v")]
+        assert eng.kv_plan.bytes_per_slot * eng.capacity == sum(
+            a.nbytes for a in kv)
 
     def test_submit_rejects_overflowing_request(self):
         eng = _engine(capacity=1)

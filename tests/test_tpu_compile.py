@@ -14,17 +14,28 @@ chain length 576 and at 128 (the TD-attention head-dim clamp).
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers all
 import this file.
+
+Beside the kernels, the serving engine's whole decode program is compiled
+at those widths (2 layers), to pin how it treats the KV cache it is
+donated: in place, with no loop over rows and no copy of a layer's cache.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.configs as cfgs
+from repro.kernels import common as kernels_common
 from repro.kernels.decode_gqa.decode_gqa import decode_gqa_pallas
 from repro.kernels.flash_attn.flash_attn import flash_attn_pallas
 from repro.kernels.td_vmm.td_vmm import td_vmm_pallas
+from repro.launch import steps
+from repro.models import common, get_api, transformer
 
 D_MODEL, D_FF, VOCAB = 2048, 11008, 151936
 HQ, HKV, HD = 16, 2, 128
@@ -100,3 +111,52 @@ def test_decode_gqa_compiles(one_chip):
     _compile(fn, spec((b, HQ, HD), jnp.bfloat16),
              spec((b, s, HKV, HD), jnp.bfloat16),
              spec((b, s, HKV, HD), jnp.bfloat16), spec((b,), jnp.int32))
+
+
+def test_serve_step_writes_kv_cache_in_place(one_chip, monkeypatch):
+    """The continuous-batching engine's decode program (per-row caches,
+    capacity 48, 2048-token slots, the state donated as the engine donates
+    it) at qwen2.5-3b widths with 2 layers: each layer's lane-dense cache
+    is read by the decode kernel as it is and the new row is written into
+    it in place."""
+    # the kernels inside the step resolve their mode from the backend:
+    # compile them as on the chip, from this CPU process
+    monkeypatch.setattr(kernels_common, "default_interpret", lambda: False)
+    b, s_cache, n_layers = 48, 2048, 2
+    arch = cfgs.get("qwen2.5-3b")
+    cfg = dataclasses.replace(arch.model, n_layers=n_layers)
+    arch = dataclasses.replace(arch, model=cfg)
+    pol = common.resolve_arch_policy(arch)
+    assert pol.mode == "precise"
+    step = steps.build_serve_step(
+        arch, steps.ShapeCfg("serve", s_cache, b, "decode"))
+    params = jax.eval_shape(lambda: get_api(cfg)["init"](
+        jax.random.key(0), cfg, pol, jnp.bfloat16))
+    caches = jax.eval_shape(lambda: transformer.init_caches(
+        b, s_cache, cfg, jnp.bfloat16, pol=pol, per_row_idx=True))
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        on_chip(params), on_chip(jax.ShapeDtypeStruct((b, 1), jnp.int32)),
+        on_chip({"layers": caches, "enc_out": None})).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                 # decode_gqa, compiled
+
+    layer_shape = (b, s_cache, HKV * HD)
+    assert {c["k"].shape for c in caches} == {layer_shape}
+    layer_bytes = b * s_cache * HKV * HD * 2
+    entry = text[text.index("\nENTRY"):]
+    whole = re.compile(r"= \w+\[([0-9,]+)\]\S* (reshape|copy)\(")
+    copies = [ln for ln in entry.splitlines()
+              if (m := whole.search(ln)) and np.prod(
+                  [int(x) for x in m.group(1).split(",")]) == np.prod(
+                      layer_shape)]
+    assert not copies, copies                        # no re-tiling copy
+    loops = [ln for ln in text.splitlines() if " while(" in ln
+             and ("scatter" in ln or f"[{b},{s_cache}," in ln)]
+    assert not loops, loops                          # no loop over rows
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes
+    # every byte of the pool (K and V of each layer) is aliased in place
+    assert mem.alias_size_in_bytes >= 2 * n_layers * layer_bytes
