@@ -1,18 +1,13 @@
-"""Weights from the seed, and the plain reference the served tokens are
-checked against.
+"""The qwen2 family (`model_type` "qwen2"): weights from the seed, and
+the plain reference the served tokens are checked against.
 
 The benchmark makes the weights itself, in one jitted call on the device,
 in the tree the serving engine takes, and hands the same arrays to the
 engine and to the reference.  The reference imports nothing of the
-program: it is a straightforward qwen2-style decoder written here
-(RMSNorm, RoPE, GQA causal attention over the whole sequence, SwiGLU),
-run once per request over its prompt and served tokens.  It follows the
-arithmetic the configuration states: bf16 weights and activations,
-matmuls accumulated in f32 and rounded to bf16, norms, RoPE and softmax
-in f32.
-
-`lowp=True` is the control: the same reference with every matmul input,
-activation and weight, rounded through float8 e4m3 first.
+program: it is a straightforward qwen2 decoder written here (RMSNorm,
+RoPE, GQA causal attention with q/k/v biases over the whole sequence,
+SwiGLU), run once per request over its prompt and served tokens, with
+the arithmetic of `refs/common.py`.  `lowp=True` is its control.
 """
 from __future__ import annotations
 
@@ -22,8 +17,34 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-BF16 = jnp.bfloat16
-F32 = jnp.float32
+from . import Dims
+from .common import BF16, _attention, _linear, _rmsnorm, _rope
+
+
+def stated(m) -> dict:
+    """The program's value of each published key, from its model config."""
+    return {"num_hidden_layers": m.n_layers, "hidden_size": m.d_model,
+            "num_attention_heads": m.n_heads,
+            "num_key_value_heads": m.n_kv_heads,
+            "intermediate_size": m.d_ff, "vocab_size": m.vocab,
+            "rope_theta": m.rope_theta, "rms_norm_eps": m.rms_eps,
+            "tie_word_embeddings": m.tie_embeddings}
+
+
+def _head_dim(cfg: dict) -> int:
+    return cfg.get("head_dim") or \
+        cfg["hidden_size"] // cfg["num_attention_heads"]
+
+
+def dims(cfg: dict) -> Dims:
+    """Sizes for the work counts; every linear of every layer and the LM
+    head multiply each token (the embedding lookup multiplies nothing)."""
+    per_layer = sum(k * n for k, n, _, _ in _linears(cfg).values())
+    return Dims(layers=cfg["num_hidden_layers"],
+                heads=cfg["num_attention_heads"],
+                kv_heads=cfg["num_key_value_heads"], head_dim=_head_dim(cfg),
+                matmul_params=cfg["num_hidden_layers"] * per_layer
+                + cfg["hidden_size"] * cfg["vocab_size"])
 
 
 # ---------------------------------------------------------------------------
@@ -33,7 +54,7 @@ def _linears(cfg: dict) -> dict:
     """name -> (K, N, bias, std) of one layer's matmuls."""
     d, f = cfg["hidden_size"], cfg["intermediate_size"]
     hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = d // hq
+    hd = _head_dim(cfg)
     return {"attn.wq": (d, hq * hd, True, d ** -0.5),
             "attn.wk": (d, hkv * hd, True, d ** -0.5),
             "attn.wv": (d, hkv * hd, True, d ** -0.5),
@@ -92,57 +113,10 @@ def _make_weights(words, cfg):
 # ---------------------------------------------------------------------------
 # the reference
 # ---------------------------------------------------------------------------
-def _lowp(a):
-    return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
-
-
-def _linear(p, x, lowp: bool, out_f32: bool = False):
-    """x (T, K) bf16 -> (T, N): bf16, or f32 before the last rounding."""
-    w = p["w"]
-    if lowp:
-        x, w = _lowp(x), _lowp(w)
-    y = jnp.dot(x, w, preferred_element_type=F32)
-    if out_f32:
-        return y
-    y = y.astype(BF16)
-    return y + p["b"] if "b" in p else y
-
-
-def _rmsnorm(p, x, eps: float):
-    xf = x.astype(F32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps) * p["scale"].astype(F32)
-            ).astype(x.dtype)
-
-
-def _rope(x, pos, theta: float):
-    """x (T, H, D); rotate-half RoPE at integer positions pos (T,)."""
-    hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
-    ang = pos[:, None].astype(F32) * inv
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x.astype(F32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def _attention(q, k, v):
-    """Causal GQA softmax attention in f32; q (T, Hq, D), k/v (T, Hkv, D)."""
-    t, hq, hd = q.shape
-    g = hq // k.shape[1]
-    k = jnp.repeat(k.astype(F32), g, axis=1)
-    v = jnp.repeat(v.astype(F32), g, axis=1)
-    with jax.default_matmul_precision("highest"):
-        s = jnp.einsum("thd,uhd->htu", q.astype(F32) * hd ** -0.5, k)
-        s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("htu,uhd->thd", p, v).astype(q.dtype)
-
-
 def _layer(lp, x, pos, *, cfg, lowp):
     hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    t, d = x.shape
-    hd = d // hq
+    t = x.shape[0]
+    hd = _head_dim(cfg)
     lin = functools.partial(_linear, lowp=lowp)
     eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
     h = _rmsnorm(lp["ln1"], x, eps)
@@ -190,9 +164,3 @@ class Reference:
                                  jnp.asarray(start, jnp.int32))
         return np.asarray(lg)[lo - start:hi - start]
 
-
-def gaps(ref_logits: np.ndarray, chosen: np.ndarray) -> np.ndarray:
-    """How far each chosen token's reference logit lies below the
-    reference's best, per position."""
-    best = ref_logits.max(-1)
-    return best - np.take_along_axis(ref_logits, chosen[:, None], 1)[:, 0]

@@ -13,7 +13,9 @@ seed on the device, builds the serving engine at the mix's sizes
 the window and, for ``--seconds``, submits each request when it is due and
 steps the engine while it has work.  Then it reads the device's peak
 memory, frees the engine, and checks a sample of the served tokens
-against the plain reference (`chipbench/model.py`).
+against the plain reference.  The weight draw, the reference and what the
+program is held to come from the configuration's family,
+`chipbench/refs/<model_type>.py` (`refs.load`).
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
@@ -51,6 +53,7 @@ sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(BENCH))
 
 import record  # noqa: E402
+import refs  # noqa: E402
 import traffic  # noqa: E402
 
 OUT = ROOT / ".chipbench_out"
@@ -95,22 +98,19 @@ def seed_words(seed: int) -> tuple[int, int]:
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
-def build_arch(cfg: dict):
-    """The registry's architecture in the configuration's mode, held to
-    the arithmetic the configuration states."""
-    import repro.configs as cfgs
-    from repro.launch import td_cli
+def build_arch(cfg: dict, arch=None):
+    """The registry's architecture in the configuration's mode (or `arch`,
+    the CPU rehearsal's), held to the arithmetic the configuration states:
+    each published key its family states, and the mode of the matmuls."""
     from repro.models import common
-    arch = td_cli.apply_td_args(cfgs.get(cfg["registry"]), cfg["mode"],
-                                None)
-    m = arch.model
-    stated = {"num_hidden_layers": m.n_layers, "hidden_size": m.d_model,
-              "num_attention_heads": m.n_heads,
-              "num_key_value_heads": m.n_kv_heads,
-              "intermediate_size": m.d_ff, "vocab_size": m.vocab,
-              "rope_theta": m.rope_theta, "rms_norm_eps": m.rms_eps,
-              "tie_word_embeddings": m.tie_embeddings}
-    off = {k: (v, cfg[k]) for k, v in stated.items() if cfg[k] != v}
+    if arch is None:
+        import repro.configs as cfgs
+        from repro.launch import td_cli
+        arch = td_cli.apply_td_args(cfgs.get(cfg["registry"]), cfg["mode"],
+                                    None)
+    stated = refs.load(cfg).stated(arch.model)
+    off = {k: (v, refs.at(cfg, k)) for k, v in stated.items()
+           if refs.at(cfg, k) != v}
     if off:
         raise RuntimeError(f"the program departs from the configuration: "
                            f"{off}")
@@ -292,9 +292,9 @@ def check(run: record.Run, params, cfg: dict, s_cache: int, seed: int,
     each served token's reference logit lies below the reference's best.
     With `control` also the control's: the same gap of the token that the
     float8 reference puts first, on the same prompts and tokens."""
-    import model
+    from refs.common import gaps
     n_rows = -(-run.mix["output"]["max"] // 128) * 128
-    ref = model.Reference(cfg, s_cache, min(n_rows, s_cache))
+    ref = refs.load(cfg).Reference(cfg, s_cache, min(n_rows, s_cache))
     vocab = cfg["vocab_size"]
     out = {"served": [], "control": [], "control_ids": []}
     for r in check_sample(run, seed, run.mix["check"]["tokens"]):
@@ -305,10 +305,10 @@ def check(run: record.Run, params, cfg: dict, s_cache: int, seed: int,
         toks[:len(seq)] = seq
         lo, hi = r.prompt_len - 1, r.prompt_len - 1 + len(ids)
         lg = ref.logits(params, toks, lo, hi)
-        out["served"].append(model.gaps(lg, np.clip(ids, 0, vocab - 1)))
+        out["served"].append(gaps(lg, np.clip(ids, 0, vocab - 1)))
         if control:
             pick = ref.logits(params, toks, lo, hi, lowp=True).argmax(-1)
-            out["control"].append(model.gaps(lg, pick))
+            out["control"].append(gaps(lg, pick))
             out["control_ids"].append(pick)
     return out
 
@@ -369,12 +369,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     cell = next(w for w in bench["workloads"] if w["name"] == workload)
     cfg = cfg or load_json(BENCH / "configs" / f"{cell['config']}.json")
     mix = mix or traffic.load(cell["traffic"])
+    family = refs.load(cfg)
     compiles = count_compiles()
     t_import = time.perf_counter() - T_START
 
     t = time.perf_counter()
-    arch = arch or build_arch(cfg)
-    params = __import__("model").make_weights(cfg, seed_words(seed))
+    arch = build_arch(cfg, arch)
+    params = family.make_weights(cfg, seed_words(seed))
     jax.block_until_ready(params)
     from repro.launch.scheduler import ContinuousBatchingEngine
     sizes = mix["engine"]

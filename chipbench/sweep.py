@@ -19,8 +19,9 @@ import json
 import sys
 import time
 
-import run as harness
 import record
+import refs
+import run as harness
 import traffic
 
 
@@ -63,9 +64,8 @@ def main(argv=None) -> int:
                             f"{cell['config']}.json")
     mix = traffic.load(cell["traffic"])
     from repro.launch.scheduler import ContinuousBatchingEngine
-    import model
     seeds = [int(s) for s in args.seeds.split(",")]
-    params = model.make_weights(cfg, harness.seed_words(seeds[0]))
+    params = refs.load(cfg).make_weights(cfg, harness.seed_words(seeds[0]))
     sizes = mix["engine"]
     eng = ContinuousBatchingEngine(harness.build_arch(cfg),
                                    capacity=sizes["capacity"],

@@ -1,6 +1,7 @@
 """The CPU rehearsal's small model and mixes: the registry's qwen2.5-3b
 smoke preset (2 layers, width 64) under each cell's arrival kind, at
-sizes a test run can hold."""
+sizes a test run can hold.  Its configuration is the cell's, with the
+program's value of each key the family states (`refs.load(cfg).stated`)."""
 from __future__ import annotations
 
 import json
@@ -12,10 +13,23 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(BENCH))
 
+import refs  # noqa: E402
 import run as harness  # noqa: E402
 
 PEAKS = {"bf16_flops_per_s": 1e12, "int8_ops_per_s": 2e12,
          "hbm_bytes_per_s": 1e11, "hbm_bytes": 1e9}
+
+
+def assign(cfg: dict, values: dict) -> dict:
+    """`cfg` with each published key set to its value; a dotted key names
+    one inside a nested group, made where it is missing."""
+    for key, value in values.items():
+        *groups, leaf = key.split(".")
+        group = cfg
+        for g in groups:
+            group = group.setdefault(g, {})
+        group[leaf] = value
+    return cfg
 
 
 def arch_and_cfg():
@@ -23,14 +37,9 @@ def arch_and_cfg():
     from repro.launch import td_cli
     arch = td_cli.apply_td_args(cfgs.get_smoke("qwen2.5-3b"), "precise",
                                 None)
-    m = arch.model
     cfg = json.loads((BENCH / "configs" /
                       "qwen2.5-3b-precise.json").read_text())
-    cfg.update(num_hidden_layers=m.n_layers, hidden_size=m.d_model,
-               num_attention_heads=m.n_heads, num_key_value_heads=m.n_kv_heads,
-               intermediate_size=m.d_ff, vocab_size=m.vocab,
-               rope_theta=m.rope_theta, rms_norm_eps=m.rms_eps)
-    return arch, cfg
+    return arch, assign(cfg, refs.load(cfg).stated(arch.model))
 
 
 MIXES = {
@@ -53,9 +62,11 @@ CELLS = {"poisson": "qwen2.5-3b-precise.chat",
 
 
 def run(kind: str, seed: int = 7, seconds: float = 3.0,
-        trace: bool = False, control: bool = False):
-    """One run of the cell whose arrivals are `kind`, at the smoke size."""
-    arch, cfg = arch_and_cfg()
+        trace: bool = False, control: bool = False, cfg: dict | None = None):
+    """One run of the cell whose arrivals are `kind`, at the smoke size;
+    `cfg` replaces the smoke configuration."""
+    arch, smoke_cfg = arch_and_cfg()
+    cfg = cfg or smoke_cfg
     return harness.run_cell(CELLS[kind], seed, seconds, trace, cfg=cfg,
                             arch=arch, mix=MIXES[kind], control=control,
                             peaks=PEAKS)

@@ -91,13 +91,13 @@ def test_traced_window_is_lengthened_by_the_trace_stop():
     full length after the stall, for the check of `correct`."""
     import run as harness
     from repro.launch.scheduler import ContinuousBatchingEngine
-    import model
     import record
+    import refs
     import traffic
     import time
     arch, cfg = smoke.arch_and_cfg()
     mix = dict(smoke.MIXES["poisson"], trace_seconds=0.5)
-    params = model.make_weights(cfg, (1, 0))
+    params = refs.load(cfg).make_weights(cfg, (1, 0))
     sizes = mix["engine"]
     eng = ContinuousBatchingEngine(arch, capacity=sizes["capacity"],
                                    s_cache=sizes["s_cache"],

@@ -38,6 +38,24 @@ def test_decode_gqa_counts_by_hand():
     assert t == pytest.approx(36 * nbytes / 819e9)
 
 
+def test_decode_gqa_counts_with_an_explicit_head_dim():
+    # Qwen3-4B's shape: 2560 / 32 heads would be 80, its config states 128
+    cfg = {"model_type": "qwen2", "num_hidden_layers": 36,
+           "hidden_size": 2560, "num_attention_heads": 32,
+           "num_key_value_heads": 8, "head_dim": 128,
+           "intermediate_size": 9728, "vocab_size": 151936}
+    ops, nbytes = decode_gqa.work(cfg, [512])
+    assert ops == 4 * 32 * 128 * 512
+    assert nbytes == 2 * 512 * 8 * 128 * 2 + 2 * 32 * 128 * 2
+    assert nbytes == 2_113_536
+    t, by_bytes = decode_gqa.least_time(cfg, [512], PEAKS)
+    assert by_bytes == 1.0
+    assert t == pytest.approx(36 * nbytes / 819e9)
+    # q and o are 2560 x 4096, k and v 2560 x 1024
+    layer = 2560 * 4096 * 2 + 2560 * 1024 * 2 + 3 * 2560 * 9728
+    assert model.matmul_params(cfg) == 36 * layer + 2560 * 151936
+
+
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError):
         harness.device_peaks("TPU v99 imaginary")
