@@ -1,6 +1,7 @@
 """Architecture registry: `--arch <id>` resolution.
 
-Ten assigned architectures + the paper's own evaluation CNN.
+Ten assigned architectures, granite-4.0-h-micro (the served Mamba2 +
+GQA hybrid) and the paper's own evaluation CNN.
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ _MODULES = {
     "granite-moe-1b-a400m": "repro.configs.granite_moe_1b",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
     "rwkv6-1.6b": "repro.configs.rwkv6_1_6b",
+    "granite-4.0-h-micro": "repro.configs.granite_4_0_h_micro",
 }
 
 ARCH_NAMES = tuple(_MODULES)

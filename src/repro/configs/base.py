@@ -65,8 +65,14 @@ class ModelCfg:
     qk_norm: bool = False            # qwen3
     qkv_bias: bool = False           # qwen2.5
     rope_theta: float = 1e6
+    rope: bool = True                # False: no positional encoding (NoPE)
     rms_eps: float = 1e-5
     tie_embeddings: bool = False
+    # granite-style multipliers; the defaults leave the arithmetic as it is
+    embedding_multiplier: float = 1.0   # x0 = m * embed[tok]
+    residual_multiplier: float = 1.0    # x += m * branch(x)
+    logits_scaling: float = 1.0         # logits = head(x) / s
+    attention_multiplier: float | None = None   # softmax scale; None: 1/sqrt(hd)
     moe: MoECfg | None = None
     ssm: SSMCfg | None = None
     rwkv: RWKVCfg | None = None

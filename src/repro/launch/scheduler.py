@@ -69,11 +69,18 @@ asks for:
     and decode call (`engine.decode` with rows and cached tokens,
     `engine.decode_wait`, `engine.harvest`) is a `jax.profiler` span, so
     any profiler capture shows where the host spends a tick beside the
-    device ops.
+    device ops.  Where the model holds recurrent state, `engine.decode` and
+    `engine.insert` also carry its bytes (`state_bytes`).
 
-Scope: decoder-family, pure-attention, token-only models (the bucketed
-prefill relies on causal masking to keep pad junk out of the prefix;
-SSM/RWKV state and modality frontends would integrate pad positions).
+Scope: decoder-family, token-only models whose mixers are attention and
+Mamba2.  Each slot holds per-row KV for the attention layers and per-row
+conv and SSM state for the Mamba2 layers, side by side in one state
+pytree.  The bucketed prefill keeps pad junk out of both: causal masking
+for KV (the slot's fill index is the true length), and, for Mamba2, a
+prefill that stops the state at the true length; an admission overwrites
+the slot's recurrent state, which no length masks.  RWKV6 and shared
+attention (whose tied block's state is not per layer) are refused, as are
+modality frontends, which need a pad-aware prefill.
 """
 from __future__ import annotations
 
@@ -155,9 +162,10 @@ class ContinuousBatchingEngine:
         if cfg.frontend is not None:
             raise ValueError("scheduler serves token-only models (modality "
                              "frontends need pad-aware prefill)")
-        bad = {cfg.mixer_at(i) for i in range(cfg.n_layers)} - {"attn"}
+        bad = {cfg.mixer_at(i) for i in range(cfg.n_layers)} - {"attn",
+                                                                "mamba2"}
         if bad:
-            raise ValueError("scheduler requires pure-attention mixers "
+            raise ValueError("scheduler serves attention and Mamba2 mixers "
                              f"(bucketed prefill); got {sorted(bad)}")
         self.arch, self.cfg = arch, cfg
         self.clock = clock
@@ -257,6 +265,14 @@ class ContinuousBatchingEngine:
         self.done: dict[int, Request] = {}
         self.steps_run = 0
         self._reset_device_state()
+        # bytes of one slot's recurrent state (Mamba2 conv + SSM; 0 for
+        # pure attention): what an admission writes, what a tick updates
+        recurrent = {jax.tree_util.DictKey("conv"),
+                     jax.tree_util.DictKey("ssm")}
+        self.state_bytes = sum(
+            a.nbytes for path, a in
+            jax.tree_util.tree_leaves_with_path(self._state)
+            if path[-1] in recurrent) // self.capacity
 
     # ------------------------------------------------------------------
     # device state
@@ -299,7 +315,7 @@ class ContinuousBatchingEngine:
             with TraceAnnotation("engine.prefill", tokens=len(ctx),
                                  padded=self.prompt_pad):
                 tok, pstate = self._prefill(self.params, ids, n)
-            with TraceAnnotation("engine.insert"):
+            with TraceAnnotation("engine.insert", **self._state_stat(1)):
                 self._state = self._insert(self._state, pstate,
                                            jnp.asarray(slot.index, jnp.int32),
                                            n)
@@ -315,6 +331,12 @@ class ContinuousBatchingEngine:
             with TraceAnnotation("engine.first_token"):
                 first = int(tok[0, 0])
             self._record_token(req, first, now)
+
+    def _state_stat(self, rows: int) -> dict:
+        """`state_bytes` of `rows` slots, for a span; none for a model
+        without recurrent state."""
+        return {"state_bytes": rows * self.state_bytes} \
+            if self.state_bytes else {}
 
     def _record_token(self, req: Request, token: int, now: float) -> None:
         req.generated.append(token)
@@ -377,7 +399,7 @@ class ContinuousBatchingEngine:
         if not active:
             return bool(self.queue)
         self.watchdog.start(self.steps_run)
-        stats = {"rows": len(active)}
+        stats = {"rows": len(active), **self._state_stat(len(active))}
         if TraceAnnotation.is_enabled():
             stats["kv_tokens"] = sum(len(s.request.prompt)
                                      + len(s.request.generated)

@@ -1,4 +1,5 @@
-"""Attention: GQA/MQA/MHA with qk-norm, QKV bias, RoPE and KV caches, on
+"""Attention: GQA/MQA/MHA with qk-norm, QKV bias, RoPE (or none: NoPE)
+and KV caches, on
 the production fused engines — every attention call routes to one of:
 
   * `kernels.flash_attn.ops.flash_attention` — fused online-softmax Pallas
@@ -98,7 +99,11 @@ def attention(params: dict, x: jnp.ndarray, cfg: ModelCfg, pol,
     if per_row and s != 1:
         raise ValueError("per-slot (vector-idx) caches support single-token "
                          f"decode steps only, got s={s}")
-    if not is_cross:
+    if cfg.attention_multiplier is not None:
+        # the kernels scale scores by 1/sqrt(hd): fold the stated softmax
+        # scale into q instead (granite: 1/64 * 8 = 1/8, exact in bf16)
+        q = q * jnp.asarray(cfg.attention_multiplier * hd ** 0.5, q.dtype)
+    if not is_cross and cfg.rope:
         q = common.apply_rope(q, positions, cfg.rope_theta)
         if cache is None:
             k_pos = positions

@@ -6,6 +6,10 @@ via segment-sums + inter-chunk state recurrence with a lax.scan over
 chunks) — O(S * chunk) instead of O(S^2).
 Decode path: single-step recurrent update of the (H, P, N) SSM state plus a
 rolling causal-conv window, O(1) per token.
+Bucketed prefill (the serving engine's right-padded prompts, `true_len`):
+dt is 0 at every pad position, so the SSM state passes through the pads
+unchanged (exp(0 * A) = 1, increment 0), and the conv window is the last
+d_conv - 1 TRUE inputs; the state returned is the state at the true length.
 """
 from __future__ import annotations
 
@@ -47,18 +51,27 @@ def mamba2_init(key: jax.Array, cfg: ModelCfg, pol,
 
 
 def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
-                 state: jnp.ndarray | None = None
+                 state: jnp.ndarray | None = None,
+                 true_len: jnp.ndarray | None = None
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Depthwise causal conv along S.  x (B,S,C), w (K,C).  Returns output
-    and the trailing K-1 inputs (decode carry)."""
+    (in x's dtype, accumulated in f32) and the K-1 inputs before position
+    `true_len` (B,) (default S): the decode carry."""
     k = w.shape[0]
     if state is None:
         xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     else:
         xp = jnp.concatenate([state.astype(x.dtype), x], axis=1)
-    out = sum(xp[:, i:i + x.shape[1], :] * w[i] for i in range(k))
-    new_state = xp[:, -(k - 1):, :] if k > 1 else xp[:, :0, :]
-    return out + b, new_state
+    wf = w.astype(jnp.float32)
+    out = sum(xp[:, i:i + x.shape[1], :].astype(jnp.float32) * wf[i]
+              for i in range(k)) + b.astype(jnp.float32)
+    if true_len is None:
+        new_state = xp[:, xp.shape[1] - (k - 1):, :]
+    else:
+        # xp[j] is input j - (k-1): inputs true_len-k+1 .. true_len-1
+        idx = true_len[:, None] + jnp.arange(k - 1)[None]
+        new_state = jnp.take_along_axis(xp, idx[..., None], axis=1)
+    return out.astype(x.dtype), new_state
 
 
 def _segsum(a: jnp.ndarray) -> jnp.ndarray:
@@ -128,10 +141,13 @@ def ssd_chunked(x, dt, a, b_mat, c_mat, chunk: int, s0=None):
 
 def mamba2(params: dict, u: jnp.ndarray, cfg: ModelCfg, pol,
            state: dict | None = None,
-           key: jax.Array | None = None
+           key: jax.Array | None = None,
+           true_len: jnp.ndarray | None = None
            ) -> tuple[jnp.ndarray, dict | None]:
     """u (B,S,d) -> (y, new_state).  state={'conv':..., 'ssm':...} enables
-    O(1)-per-token decode (S must be 1 in that case)."""
+    O(1)-per-token decode (S must be 1 in that case), or, with S > 1, a
+    prefill into it; `true_len` (B,) then stops the state at each row's
+    true length (the rows are right-padded)."""
     di, nh, hp, ns, dc = dims(cfg)
     b, s, _ = u.shape
     k1, k2 = (common.fold_key(key, i) for i in range(2))
@@ -141,10 +157,13 @@ def mamba2(params: dict, u: jnp.ndarray, cfg: ModelCfg, pol,
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + params["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
+    if true_len is not None:
+        dt = jnp.where((jnp.arange(s)[None] < true_len[:, None])[..., None],
+                       dt, 0.0)
 
     conv_state = state["conv"] if state is not None else None
     xbc_c, new_conv = _causal_conv(xbc, params["conv_w"], params["conv_b"],
-                                   conv_state)
+                                   conv_state, true_len)
     xbc_c = jax.nn.silu(xbc_c)
     x, b_mat, c_mat = jnp.split(xbc_c, [di, di + ns], axis=-1)
     xh = x.reshape(b, s, nh, hp).astype(jnp.float32)
@@ -158,7 +177,7 @@ def mamba2(params: dict, u: jnp.ndarray, cfg: ModelCfg, pol,
         # prefill into a decode state: chunked SSD seeded with the carry
         y, s_final = ssd_chunked(xh, dt, a, b_f, c_f, cfg.ssm.chunk,
                                  s0=state["ssm"].astype(jnp.float32))
-        new_state = {"conv": new_conv,
+        new_state = {"conv": new_conv.astype(state["conv"].dtype),
                      "ssm": s_final.astype(state["ssm"].dtype)}
     else:
         # single-step recurrence
@@ -171,18 +190,23 @@ def mamba2(params: dict, u: jnp.ndarray, cfg: ModelCfg, pol,
         y = jnp.einsum("bn,bhpn->bhp", c_f[:, 0], s_new)[:, None]
         y = y.reshape(b, 1, nh, hp)
         s_final = s_new
-        new_state = {"conv": new_conv, "ssm": s_final.astype(state["ssm"].dtype)}
+        new_state = {"conv": new_conv.astype(state["conv"].dtype),
+                     "ssm": s_final.astype(state["ssm"].dtype)}
 
     y = y + params["d_skip"].astype(jnp.float32)[None, None, :, None] * xh
-    y = y.reshape(b, s, di).astype(u.dtype)
-    y = common.rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.rms_eps)
+    # the gated norm in f32, as the SSM output is: rounding y to the
+    # activations' dtype first is amplified where the gated rows are small
+    g = y.reshape(b, s, di) * jax.nn.silu(z.astype(jnp.float32))
+    y = common.rmsnorm(params["norm"], g, cfg.rms_eps).astype(u.dtype)
     out = common.dense(params["out_proj"], y, pol, k2)
     if state is None:
         new_state = None
     return out, new_state
 
 
-def init_state(b: int, cfg: ModelCfg, dtype=jnp.float32) -> dict:
+def init_state(b: int, cfg: ModelCfg, conv_dtype=jnp.float32) -> dict:
+    """Zero state of b rows: the conv window in `conv_dtype` (that of the
+    activations it holds), the SSM state in f32."""
     di, nh, hp, ns, dc = dims(cfg)
-    return {"conv": jnp.zeros((b, dc - 1, di + 2 * ns), dtype),
-            "ssm": jnp.zeros((b, nh, hp, ns), dtype)}
+    return {"conv": jnp.zeros((b, dc - 1, di + 2 * ns), conv_dtype),
+            "ssm": jnp.zeros((b, nh, hp, ns), jnp.float32)}

@@ -47,13 +47,17 @@ def _dec_prefill(params, batch, cfg: ModelCfg, pol, s_cache: int,
                  key=None, cache_dtype=jnp.bfloat16, true_len=None):
     b = batch["tokens"].shape[0]
     caches = transformer.init_caches(b, s_cache, cfg, cache_dtype, pol=pol)
+    tl = None
+    if true_len is not None:
+        tl = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32), (b,))
     logits, caches, _ = transformer.forward(params, batch, cfg, pol,
-                                            caches=caches, key=key)
+                                            caches=caches, key=key,
+                                            true_len=tl)
     if true_len is not None:
         # bucket-padded prompts (serving engine): causal masking keeps every
-        # row < true_len clean of the pad junk, so the next-token logits
-        # live at the TRUE last prompt position, not the padded one
-        tl = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32), (b,))
+        # row < true_len clean of the pad junk, and recurrent mixers stop
+        # their state at true_len, so the next-token logits live at the
+        # TRUE last prompt position, not the padded one
         last = jnp.take_along_axis(logits, tl[:, None, None] - 1, axis=1)
         return last, {"layers": caches, "enc_out": None}
     return logits[:, -1:], {"layers": caches, "enc_out": None}

@@ -4,6 +4,10 @@ attention-free (rwkv6) assigned architectures via per-layer mixer dispatch.
 Layer anatomy (pre-norm residual):
     x += mixer(ln1(x))      mixer in {attn, shared_attn, mamba2, rwkv6}
     x += ffn(ln2(x))        ffn   in {swiglu, moe, rwkv_chanmix}
+Granite-style multipliers (`ModelCfg`): the embedding is scaled by
+`embedding_multiplier`, each branch by `residual_multiplier` before it is
+added, and the logits divided by `logits_scaling`; at their defaults
+(1.0) none of them is applied.
 
 "shared_attn" (zamba2) applies one weight-tied attention block at several
 depths (per-site norms are private, block weights shared — stored once at
@@ -107,7 +111,8 @@ def _layer_apply(lp: dict, shared: dict | None, x: jnp.ndarray,
                  cache: dict | None,
                  key: jax.Array | None,
                  shared_pol=None,
-                 attn_pols=None) -> tuple[jnp.ndarray, dict | None, dict]:
+                 attn_pols=None,
+                 true_len=None) -> tuple[jnp.ndarray, dict | None, dict]:
     mix = cfg.mixer_at(i)
     aux: dict = {}
     kmix = common.fold_key(key, 2 * i)
@@ -126,13 +131,14 @@ def _layer_apply(lp: dict, shared: dict | None, x: jnp.ndarray,
             positions, cache=cache, key=kmix, attn_pols=attn_pols)
     elif mix == "mamba2":
         y, new_cache = mamba2.mamba2(lp["mamba"], h, cfg, pol,
-                                     state=cache, key=kmix)
+                                     state=cache, key=kmix,
+                                     true_len=true_len)
     elif mix == "rwkv6":
         y, new_cache = rwkv6.timemix(lp["timemix"], h, cfg, pol,
                                      state=cache, key=kmix)
     else:
         raise ValueError(mix)
-    x = x + y
+    x = x + _residual(y, cfg)
 
     fk = _ffn_kind(cfg, i)
     if fk == "none":
@@ -149,19 +155,29 @@ def _layer_apply(lp: dict, shared: dict | None, x: jnp.ndarray,
                                   state=cm_state, key=kffn)
         if new_cache is not None and cm_new is not None:
             new_cache = {**new_cache, **cm_new}
-    return x + y, new_cache, aux
+    return x + _residual(y, cfg), new_cache, aux
+
+
+def _residual(y: jnp.ndarray, cfg: ModelCfg) -> jnp.ndarray:
+    m = cfg.residual_multiplier
+    return y if m == 1.0 else y * jnp.asarray(m, y.dtype)
 
 
 def forward(params: dict, batch: dict, cfg: ModelCfg, pol,
             caches: list | None = None,
             positions: jnp.ndarray | None = None,
             key: jax.Array | None = None,
-            remat: str = "none"
+            remat: str = "none",
+            true_len=None
             ) -> tuple[jnp.ndarray, list | None, dict]:
     """Returns (logits, new_caches, aux).  batch: {"tokens": (B,S)} plus
-    optional {"embeds": (B,Nv,d_f)} for stub frontends."""
+    optional {"embeds": (B,Nv,d_f)} for stub frontends.  `true_len` (B,)
+    marks right-padded prompts filling `caches`: recurrent mixers stop
+    their state at each row's true length."""
     tokens = batch["tokens"]
     x = common.embed(params["embed"], tokens)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.frontend is not None and "embeds" in batch:
         emb = common.dense(params["adapter"], batch["embeds"],
                            common.pol_top(pol))
@@ -181,7 +197,7 @@ def forward(params: dict, batch: dict, cfg: ModelCfg, pol,
         return _layer_apply(lp, shared, xx, cfg, common.pol_at(pol, i), i,
                             positions, cache, lkey,
                             shared_pol=common.pol_top(pol),
-                            attn_pols=attn_pols)
+                            attn_pols=attn_pols, true_len=true_len)
 
     if remat == "full":
         run_layer = jax.checkpoint(run_layer, static_argnums=(3,))
@@ -208,7 +224,8 @@ def forward(params: dict, batch: dict, cfg: ModelCfg, pol,
                                        positions, cache_i,
                                        common.fold_key(kk, idx),
                                        shared_pol=common.pol_top(pol),
-                                       attn_pols=attn_pols)
+                                       attn_pols=attn_pols,
+                                       true_len=true_len)
             return (xx, kk), (nc, aux)
 
         body = scan_body
@@ -237,6 +254,8 @@ def forward(params: dict, batch: dict, cfg: ModelCfg, pol,
     else:
         logits = common.dense(params["lm_head"], x, common.pol_top(pol),
                               common.fold_key(key, 10_000))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     # keep the (huge) logits vocab-sharded; CE's logsumexp reduces over it
     logits = common.maybe_constrain(
         logits, common.batch_sharding_axes(), None, "model")
@@ -249,7 +268,8 @@ def init_caches(b: int, s_cache: int, cfg: ModelCfg,
     heterogeneous NetworkPolicy unrolls layers, so its caches must stay a
     per-layer list even when cfg.scan_layers is set (pol=None keeps the
     config-only behavior).  `per_row_idx` builds the serving engine's
-    ragged-slot attention caches (one fill index per batch row)."""
+    ragged-slot attention caches (one fill index per batch row).  Mamba2
+    layers hold their conv window in `dtype` and their SSM state in f32."""
     caches = []
     for i in range(cfg.n_layers):
         mix = cfg.mixer_at(i)
@@ -257,7 +277,7 @@ def init_caches(b: int, s_cache: int, cfg: ModelCfg,
             caches.append(attention.init_cache(b, s_cache, cfg, dtype,
                                                per_row_idx=per_row_idx))
         elif mix == "mamba2":
-            caches.append(mamba2.init_state(b, cfg, jnp.float32))
+            caches.append(mamba2.init_state(b, cfg, dtype))
         elif mix == "rwkv6":
             caches.append(rwkv6.init_state(b, cfg, jnp.float32))
     if _can_scan(cfg, pol):

@@ -122,23 +122,31 @@ def plan_kv_cache(cfg, capacity: int, s_cache: int, *, block: int = 128,
                   dtype_bytes: int = 2, weight_bytes: float = 0.0,
                   budget_frac: float = 0.9,
                   hbm_bytes: float | None = None) -> KVCachePlan:
-    """Size the serve engine's KV slots against the device memory.
+    """Size the serve engine's slots against the device memory.
 
-    cfg: a ModelCfg (uses n_layers/mixer pattern/n_kv_heads/hd).  The
+    cfg: a ModelCfg (uses n_layers/mixer pattern/n_kv_heads/hd/ssm).  The
     budget is `budget_frac` of (hbm_bytes - weight_bytes), with
     `hbm_bytes` the device's limit (`device_bytes_limit`); None (no limit
     reported) leaves the requested capacity uncapped.  Per-slot bytes are
     K+V per attention layer at `dtype_bytes` per element, with the
     sequence rounded up to `block`-token blocks and each head to whole
     128-lane blocks (the engine's lane-dense per-row cache,
-    `models.attention.init_cache`).
+    `models.attention.init_cache`), plus each Mamba2 layer's recurrent
+    state: the conv window at `dtype_bytes` and the f32 SSM state
+    (`models.mamba2.init_state`).
     """
-    n_attn = sum(1 for i in range(cfg.n_layers)
-                 if cfg.mixer_at(i) in ("attn", "shared_attn"))
+    mixers = [cfg.mixer_at(i) for i in range(cfg.n_layers)]
+    n_attn = sum(1 for m in mixers if m in ("attn", "shared_attn"))
     blocks = max(1, -(-s_cache // block))
     s_pad = blocks * block
     per_slot = (2 * n_attn * s_pad * cfg.n_kv_heads * round_up(cfg.hd, LANES)
                 * dtype_bytes)
+    if "mamba2" in mixers:
+        ssm = cfg.ssm
+        d_inner = ssm.expand * cfg.d_model
+        conv = (ssm.d_conv - 1) * (d_inner + 2 * ssm.d_state) * dtype_bytes
+        state = d_inner * ssm.d_state * 4
+        per_slot += mixers.count("mamba2") * (conv + state)
     if hbm_bytes is None:
         budget, max_slots = None, capacity
     else:

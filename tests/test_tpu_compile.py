@@ -17,7 +17,9 @@ import this file.
 
 Beside the kernels, the serving engine's whole decode program is compiled
 at those widths (2 layers), to pin how it treats the KV cache it is
-donated: in place, with no loop over rows and no copy of a layer's cache.
+donated: in place, with no loop over rows and no copy of a layer's cache;
+and granite-4.0-h-micro's hybrid decode program (a Mamba2 and an
+attention layer at its widths), to pin the same of its recurrent state.
 """
 import dataclasses
 import os
@@ -160,3 +162,44 @@ def test_serve_step_writes_kv_cache_in_place(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < layer_bytes
     # every byte of the pool (K and V of each layer) is aliased in place
     assert mem.alias_size_in_bytes >= 2 * n_layers * layer_bytes
+
+
+def test_hybrid_serve_step_updates_state_in_place(one_chip, monkeypatch):
+    """The decode program of granite-4.0-h-micro's hybrid (one Mamba2 and
+    one attention layer at its published widths, capacity 32, 2560-token
+    slots, the state donated as the engine donates it): the per-slot conv
+    and f32 SSM state is updated in place beside the KV cache, with no
+    copy of a layer's state and the whole pool aliased."""
+    monkeypatch.setattr(kernels_common, "default_interpret", lambda: False)
+    b, s_cache = 32, 2560
+    arch = cfgs.get("granite-4.0-h-micro")
+    cfg = dataclasses.replace(arch.model, n_layers=2,
+                              layer_pattern=("mamba2", "attn"))
+    arch = dataclasses.replace(arch, model=cfg)
+    pol = common.resolve_arch_policy(arch)
+    step = steps.build_serve_step(
+        arch, steps.ShapeCfg("serve", s_cache, b, "decode"))
+    params = jax.eval_shape(lambda: get_api(cfg)["init"](
+        jax.random.key(0), cfg, pol, jnp.bfloat16))
+    caches = jax.eval_shape(lambda: transformer.init_caches(
+        b, s_cache, cfg, jnp.bfloat16, pol=pol, per_row_idx=True))
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        on_chip(params), on_chip(jax.ShapeDtypeStruct((b, 1), jnp.int32)),
+        on_chip({"layers": caches, "enc_out": None})).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                 # decode_gqa, compiled
+    ssm = caches[0]["ssm"]
+    assert ssm.shape == (b, 64, 64, 128) and ssm.dtype == jnp.float32
+    ssm_bytes = int(np.prod(ssm.shape)) * 4
+    entry = text[text.index("\nENTRY"):]
+    copies = [ln for ln in entry.splitlines()
+              if re.search(r"= f32\[32,64,64,128\]\S* copy\(", ln)]
+    assert not copies, copies                        # no copy of the state
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < ssm_bytes
+    pool = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for c in caches for k, a in c.items() if k != "idx")
+    assert mem.alias_size_in_bytes >= pool
